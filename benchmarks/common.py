@@ -9,8 +9,12 @@ from typing import Callable, Dict, List
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import AMIHIndex, pack_bits
 from repro.data import synthetic_binary_codes, synthetic_queries
+
+# every benchmark CLI imports this module before it compiles anything
+enable_compile_cache()
 
 ART_DIR = os.environ.get("REPRO_BENCH_DIR", "artifacts/bench")
 

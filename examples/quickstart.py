@@ -44,9 +44,10 @@ def main():
 
     t0 = time.perf_counter()
     # verify_backend="pallas" puts grouped candidate verification on
-    # device (one kernel launch per z-group and tuple step); "numpy"
-    # (the default) does one vectorized host popcount per step instead —
-    # the right choice off-TPU, where Pallas runs in interpret mode.
+    # device (one launch per z-group and tuple step): the Pallas kernel
+    # on a TPU, the same math as plain XLA elsewhere. "numpy" (the
+    # default) does one vectorized host popcount per step instead — the
+    # right choice off-TPU, where a launch per step buys nothing.
     amih = make_engine("amih", db, p)
     print(f"indexed in {time.perf_counter() - t0:.2f}s "
           f"(m={amih.index.m} tables, paper's m = p/log2 n; "

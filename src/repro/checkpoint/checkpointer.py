@@ -32,8 +32,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from .. import jax_compat
-
 __all__ = ["Checkpointer", "latest_step", "restore", "save"]
 
 _PREFIX = "step_"
@@ -71,7 +69,7 @@ def save(
         shutil.rmtree(tmp)
     os.makedirs(tmp)
     try:
-        leaves_with_path = jax_compat.tree_flatten_with_path(tree)[0]
+        leaves_with_path = jax.tree.flatten_with_path(tree)[0]
         arrays: Dict[str, np.ndarray] = {}
         manifest_leaves: List[Dict[str, Any]] = []
         for path, leaf in leaves_with_path:
@@ -134,7 +132,7 @@ def restore(
     with open(os.path.join(path, "manifest.json")) as f:
         manifest = json.load(f)
     data = np.load(os.path.join(path, "arrays.npz"))
-    leaves_with_path, treedef = jax_compat.tree_flatten_with_path(template)
+    leaves_with_path, treedef = jax.tree.flatten_with_path(template)
     stored = {l["key"]: l for l in manifest["leaves"]}
     out = []
     for p, leaf in leaves_with_path:

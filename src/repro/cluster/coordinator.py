@@ -664,6 +664,7 @@ class ClusterEngine(SearchEngine):
                 "host": h,
                 "addr": f"{hd.addr[0]}:{hd.addr[1]}",
                 "rows": int(hd.info.get("n", 0)),
+                "platform": hd.info.get("platform"),
                 "shards": st.shards,
                 "rpc_ms": round(rpc_s * 1e3, 3),
                 "bound_frames": hd.bound_frames,

@@ -14,6 +14,12 @@ too — fork would quietly share page-cache state the wire protocol is
 supposed to carry. Workers announce their bound ``(host, port)`` back
 over a pipe before serving.
 
+Each worker's JAX platform is set in the child's own environment
+(``JAX_PLATFORMS``, ``platform="cpu"`` by default) rather than left to
+JAX's fallback: on a host whose one chip belongs to the parent, a worker
+that asked for the accelerator would fail or hang. Workers report the
+platform they run on in their ``ready`` frame (``EngineStats.per_host``).
+
 ``kill_worker(i)`` SIGKILLs one worker — the failure-injection hook the
 killed-worker tests use; ``close()`` terminates the fleet (idempotent).
 """
@@ -21,13 +27,18 @@ killed-worker tests use; ``close()`` terminates the fleet (idempotent).
 from __future__ import annotations
 
 import multiprocessing as mp
+import os
 from typing import List, Tuple
 
 __all__ = ["LocalCluster"]
 
 
-def _worker_main(announce) -> None:
-    # runs in the spawned interpreter; imports resolve there
+def _worker_main(announce, platform: str) -> None:
+    # runs in the spawned interpreter, before its JAX picks a backend
+    os.environ["JAX_PLATFORMS"] = platform
+    import jax
+
+    jax.config.update("jax_platforms", platform)
     from repro.cluster.worker import serve
 
     serve(host="127.0.0.1", port=0, announce=announce)
@@ -35,11 +46,13 @@ def _worker_main(announce) -> None:
 
 class LocalCluster:
     """``hosts`` spawned loopback workers; ``addresses[i]`` is worker
-    ``i``'s ``(host, port)``."""
+    ``i``'s ``(host, port)``. Every worker runs JAX on ``platform``."""
 
-    def __init__(self, hosts: int, start_timeout: float = 120.0):
+    def __init__(self, hosts: int, start_timeout: float = 120.0,
+                 platform: str = "cpu"):
         if hosts < 1:
             raise ValueError(f"hosts must be >= 1, got {hosts}")
+        self.platform = platform
         ctx = mp.get_context("spawn")
         self.procs: List[mp.Process] = []
         self.addresses: List[Tuple[str, int]] = []
@@ -48,7 +61,8 @@ class LocalCluster:
             for _ in range(hosts):
                 parent, child = ctx.Pipe()
                 proc = ctx.Process(
-                    target=_worker_main, args=(child,), daemon=True
+                    target=_worker_main, args=(child, platform),
+                    daemon=True,
                 )
                 proc.start()
                 child.close()

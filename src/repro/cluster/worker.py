@@ -39,6 +39,7 @@ import threading
 from dataclasses import asdict
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..core.amih import AMIHStats
@@ -160,6 +161,7 @@ class WorkerServer:
                     send_frame(conn, "ready", {
                         "host": host_id, "n": engine.n,
                         "shards": plan.num_shards,
+                        "platform": jax.devices()[0].platform,
                     }, lock=send_lock)
                 elif kind == "search":
                     if engine is None:

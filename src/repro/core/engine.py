@@ -243,7 +243,9 @@ def make_engine(
                           ``probe_fused=False`` restores one launch per
                           z-group), ``probe_stream_cap``,
                           ``enumeration_cap``, ``query_cache_size``,
-                          ``overlap_verify``.
+                          ``overlap_verify``, ``device`` (where the
+                          index and its launches live; JAX's default
+                          device when None).
       - "sharded_scan"  — row-sharded exhaustive scan (repro.shard).
                           ``mesh`` | ``num_shards`` | ``plan``,
                           ``shard_axes``, ``devices``, ``chunk``.
@@ -599,6 +601,7 @@ class AMIHEngine(SearchEngine):
         probe_backend: str = "host",
         probe_stream_cap: int = 1 << 16,
         probe_fused: bool = True,
+        device: Optional[Any] = None,
         **cfg: Any,
     ) -> "AMIHEngine":
         if cfg:
@@ -608,7 +611,7 @@ class AMIHEngine(SearchEngine):
             enumeration_cap = max(8 * n, 1 << 14)
         index = AMIHIndex.build(
             db_words, p, m=m, verify_backend=verify_backend,
-            probe_backend=probe_backend,
+            device=device, probe_backend=probe_backend,
             probe_stream_cap=probe_stream_cap,
             probe_fused=probe_fused,
         )

@@ -56,7 +56,7 @@ def blockmax_scores(
     db_words: jax.Array,     # (N, W) uint32, N % blk_n == 0
     *,
     blk_n: int = DEFAULT_BLK_N,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """(B, n_blocks) per-block maxima of Eq. 3 scores."""
     B, W = q_words.shape

@@ -131,7 +131,7 @@ def flash_attention(
     window: int = 0,
     q_blk: int = DEFAULT_Q_BLK,
     kv_blk: int = DEFAULT_KV_BLK,
-    interpret: bool = True,
+    interpret: bool,
     valid_len: jax.Array | None = None,
 ) -> jax.Array:
     """Fused flash attention forward. Returns (B, Sq, Hq, D) in q.dtype.

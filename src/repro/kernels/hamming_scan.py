@@ -49,7 +49,7 @@ def _scores_kernel(q_ref, z_ref, db_ref, out_ref, *, n_words: int):
         dw = db_ref[:, w][None, :]           # (1, BLK_N) uint32
         r10 = r10 + popcount32(qw & ~dw)
         r01 = r01 + popcount32(~qw & dw)
-    z = z_ref[:].astype(jnp.float32)[:, None]
+    z = z_ref[...].astype(jnp.float32)      # (BLK_Q, 1)
     num = z - r10.astype(jnp.float32)
     den_sq = z * (z - r10.astype(jnp.float32) + r01.astype(jnp.float32))
     inv = jnp.where(den_sq > 0, jax.lax.rsqrt(jnp.where(den_sq > 0, den_sq, 1.0)), 0.0)
@@ -66,7 +66,7 @@ def hamming_scan_scores(
     *,
     blk_n: int = DEFAULT_BLK_N,
     blk_q: int = DEFAULT_BLK_Q,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """(B, W) x (N, W) -> (B, N) float32 Eq.3 cosine scores.
 
@@ -82,10 +82,14 @@ def hamming_scan_scores(
         grid=grid,
         in_specs=[
             pl.BlockSpec((blk_q, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((blk_q,), lambda i, j: (j,)),
+            pl.BlockSpec((blk_q, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((blk_n, W), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((blk_q, blk_n), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         interpret=interpret,
-    )(q_words.astype(jnp.uint32), z_q.astype(jnp.int32), db_words.astype(jnp.uint32))
+    )(
+        q_words.astype(jnp.uint32),
+        z_q.astype(jnp.int32)[:, None],
+        db_words.astype(jnp.uint32),
+    )

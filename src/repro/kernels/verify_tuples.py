@@ -12,10 +12,11 @@ a true (r1, r2)-near neighbor and (b) place it in the emission order
 
   - ``verify_tuples_grouped``: every query of an AMIH z-group at once.
     Candidates are pre-gathered into a padded (B, C, W) layout and the
-    grid is 2-D over (query, candidate-block): program (i, j) verifies
-    query i against its candidate block j. A per-query length vector
-    masks the C-padding (and whole padded query rows) in-kernel: padded
-    slots come back as key = -1. The tuple -> Eq. 3 bucket key conversion
+    grid is 2-D over (query tile, candidate-block): program (i, j)
+    verifies the 8 queries of tile i against their candidate block j. A
+    per-query length vector masks the C-padding (and whole padded query
+    rows) in-kernel: padded slots come back as key = -1. The tuple ->
+    Eq. 3 bucket key conversion
     is fused on device — each candidate returns ONE packed int32
 
         key = r10 * (p + 1) + r01        (p + 1 > any valid r01)
@@ -44,6 +45,8 @@ from .ref import popcount32
 
 DEFAULT_BLK_N = 1024
 DEFAULT_BLK_C = 128
+# query rows per grouped-verify program: one sublane tile
+DEFAULT_BLK_B = 8
 
 # Trace-time counters, keyed by kernel name: the jitted wrappers bump them
 # from their Python bodies, which only execute when jax actually traces a
@@ -68,25 +71,25 @@ def _verify_kernel(q_ref, cand_ref, r10_ref, r01_ref, *, n_words: int):
 def _verify_grouped_kernel(
     q_ref, cand_ref, len_ref, key_ref, *, n_words: int, p: int
 ):
-    """Program (i, j): query i vs its j-th candidate block.
+    """Program (i, j): query tile i vs its j-th candidate block.
 
-    q_ref (1, W) uint32; cand_ref (1, BLK_C, W) uint32; len_ref (1, 1)
-    int32 (query i's true candidate count); key_ref (1, BLK_C) int32.
+    q_ref (BLK_B, W) uint32; cand_ref (BLK_B, BLK_C, W) uint32; len_ref
+    (BLK_B, 1) int32 (each query's true candidate count); key_ref
+    (BLK_B, BLK_C) int32.
     """
-    blk_c = cand_ref.shape[1]
-    r10 = jnp.zeros((1, blk_c), dtype=jnp.int32)
-    r01 = jnp.zeros((1, blk_c), dtype=jnp.int32)
+    blk_b, blk_c = key_ref.shape
+    r10 = jnp.zeros((blk_b, blk_c), dtype=jnp.int32)
+    r01 = jnp.zeros((blk_b, blk_c), dtype=jnp.int32)
     for w in range(n_words):
-        qw = q_ref[0, w]                        # scalar uint32
-        cw = cand_ref[0, :, w][None, :]         # (1, BLK_C)
+        qw = q_ref[:, w][:, None]               # (BLK_B, 1) uint32
+        cw = cand_ref[:, :, w]                  # (BLK_B, BLK_C)
         r10 = r10 + popcount32(qw & ~cw)
         r01 = r01 + popcount32(~qw & cw)
     key = r10 * jnp.int32(p + 1) + r01
     col = pl.program_id(1) * blk_c + jax.lax.broadcasted_iota(
-        jnp.int32, (1, blk_c), 1
+        jnp.int32, (blk_b, blk_c), 1
     )
-    valid = col < len_ref[0, 0]
-    key_ref[...] = jnp.where(valid, key, jnp.int32(-1))
+    key_ref[...] = jnp.where(col < len_ref[...], key, jnp.int32(-1))
 
 
 @functools.partial(jax.jit, static_argnames=("p", "blk_c", "interpret"))
@@ -97,37 +100,43 @@ def verify_tuples_grouped(
     *,
     p: int,
     blk_c: int = DEFAULT_BLK_C,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(B, W), (B, C, W), (B,) -> packed bucket keys (B, C) int32.
 
     One launch verifies every query of a z-group against its padded
-    candidate block: 2-D grid (B, C / blk_c). Entry (i, c) is
-    ``r10 * (p + 1) + r01`` for candidate c of query i when
-    ``c < lengths[i]``, and -1 (masked padding) otherwise. C % blk_c == 0.
+    candidate block: 2-D grid (B / BLK_B, C / blk_c) over tiles of
+    ``BLK_B`` query rows (B itself when B < 8; B >= 8 is padded to a
+    multiple of 8, so each block's query axis is sublane-aligned).
+    Entry (i, c) is ``r10 * (p + 1) + r01`` for candidate c of query i
+    when ``c < lengths[i]``, and -1 (masked padding) otherwise.
+    C % blk_c == 0.
     """
     TRACE_COUNTS["verify_tuples_grouped"] += 1
     B, W = q_words.shape
     Bc, C, Wd = cand_words.shape
     assert W == Wd and B == Bc and B == lengths.shape[0]
     assert C % blk_c == 0, (C, blk_c)
-    grid = (B, C // blk_c)
-    return pl.pallas_call(
+    blk_b = min(B, DEFAULT_BLK_B)
+    Bp = -(-B // blk_b) * blk_b
+    pad = Bp - B
+    keys = pl.pallas_call(
         functools.partial(_verify_grouped_kernel, n_words=W, p=p),
-        grid=grid,
+        grid=(Bp // blk_b, C // blk_c),
         in_specs=[
-            pl.BlockSpec((1, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, blk_c, W), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((blk_b, W), lambda i, j: (i, 0)),
+            pl.BlockSpec((blk_b, blk_c, W), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((blk_b, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_c), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.int32),
+        out_specs=pl.BlockSpec((blk_b, blk_c), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Bp, C), jnp.int32),
         interpret=interpret,
     )(
-        q_words.astype(jnp.uint32),
-        cand_words.astype(jnp.uint32),
-        lengths.astype(jnp.int32)[:, None],
+        jnp.pad(q_words.astype(jnp.uint32), ((0, pad), (0, 0))),
+        jnp.pad(cand_words.astype(jnp.uint32), ((0, pad), (0, 0), (0, 0))),
+        jnp.pad(lengths.astype(jnp.int32), (0, pad))[:, None],
     )
+    return keys[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("blk_n", "interpret"))
@@ -136,7 +145,7 @@ def verify_tuples(
     cand_words: jax.Array,
     *,
     blk_n: int = DEFAULT_BLK_N,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(W,), (N, W) -> (r10, r01), each (N,) int32. N % blk_n == 0."""
     TRACE_COUNTS["verify_tuples"] += 1

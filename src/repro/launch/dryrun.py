@@ -43,7 +43,6 @@ def _cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
     """Lower+compile one cell in THIS process. Returns the report dict."""
     import jax
 
-    from repro import jax_compat
     from repro.configs import get_config
     from repro.launch.mesh import make_production_mesh
     from repro.models.common import SHAPES, shape_applicable
@@ -108,7 +107,7 @@ def _cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
             lowered = built["lower_for"](shape)
         compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        ca = jax_compat.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         hlo = compiled.as_text()
         costs = parse_hlo_costs(hlo)
         per_dev_bytes = (

@@ -8,21 +8,21 @@ jax initialization).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older versions are all-Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    # Older jax: make_mesh has no axis_types kwarg; meshes are implicitly
-    # Auto, which is exactly what the explicit call above requests.
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis Auto. The model code shards through
+    ``with_sharding_constraint`` hints, which only Auto axes accept;
+    ``jax.make_mesh`` makes Explicit axes unless told otherwise."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

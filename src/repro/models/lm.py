@@ -23,7 +23,6 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import jax_compat
 from . import ssm as ssm_lib
 from .blocks import (
     AttnCache,
@@ -163,7 +162,7 @@ def _is_pspec(x):
 
 def count_params(cfg: ArchConfig, active_only: bool = False) -> int:
     total = 0
-    for path, spec in jax_compat.tree_flatten_with_path(
+    for path, spec in jax.tree.flatten_with_path(
         model_template(cfg), is_leaf=_is_pspec
     )[0]:
         n = math.prod(spec.shape)
@@ -196,7 +195,7 @@ def logical_axes(cfg: ArchConfig):
 def init_params(cfg: ArchConfig, key: jax.Array):
     """Materialize real parameters (smoke tests / examples / training)."""
     pdt = cfg.pdtype()
-    flat, treedef = jax_compat.tree_flatten_with_path(
+    flat, treedef = jax.tree.flatten_with_path(
         model_template(cfg), is_leaf=_is_pspec
     )
     keys = jax.random.split(key, len(flat))

@@ -23,8 +23,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import jax_compat
-
 __all__ = [
     "quantize_block_int8",
     "dequantize_block_int8",
@@ -79,7 +77,7 @@ def compressed_psum_mean(
             total = jax.lax.psum(total, ax)
         n = 1
         for ax in axis_names:
-            n *= jax_compat.axis_size(ax)
+            n *= jax.lax.axis_size(ax)
         return total / n, new_r
 
     out = jax.tree.map(leaf, grads, residuals)

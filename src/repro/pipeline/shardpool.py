@@ -62,7 +62,6 @@ from __future__ import annotations
 import multiprocessing
 import sys
 import threading
-import warnings
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
@@ -437,14 +436,7 @@ class PersistentShardPool:
                       task_parent, res_child),
                 daemon=True,
             )
-            with warnings.catch_warnings():
-                # jax warns that a fork-child using jax may deadlock;
-                # these children are numpy-only by construction
-                # (_run_pool_task)
-                warnings.filterwarnings(
-                    "ignore", message=".*os.fork.*", category=RuntimeWarning
-                )
-                proc.start()
+            proc.start()
             self.forks += 1
             task_parent.close()
             res_child.close()

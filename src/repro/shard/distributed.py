@@ -35,10 +35,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .. import jax_compat
 
 from ..kernels import ops
 from .plan import ShardPlan, resolve_mesh_axes
@@ -100,7 +97,7 @@ def sharded_scan_topk(
         offset = (_shard_index(mesh, db_axes) * shard_rows).astype(jnp.int32)
         return _local_topk_then_merge(q, db_shard, offset, k, chunk, db_axes)
 
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(db_axes)),
@@ -149,7 +146,7 @@ def sharded_scan_candidates(
             gids = jax.lax.all_gather(gids, ax, axis=1, tiled=True)
         return sims, gids
 
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(axes), P(), P()),

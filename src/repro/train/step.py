@@ -24,12 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import jax_compat
 from ..models import Model, input_specs
 from ..models.common import ArchConfig, ShapeConfig
 from ..models.sharding import DEFAULT_RULES, Rules, sharding_context
 from ..optim import OptimConfig, apply_updates, init_state, state_specs
 from ..launch import shardings as sh
+from ..launch.mesh import auto_axes
 
 __all__ = ["TrainConfig", "make_train_step", "make_serve_step"]
 
@@ -69,6 +69,7 @@ def make_train_step(
       init:            (key) -> (params, opt_state) materializer
     """
     model = Model(cfg)
+    mesh = None if mesh is None else auto_axes(mesh)
     rules = dict(rules or DEFAULT_RULES)
 
     def loss_fn(params, batch):
@@ -186,6 +187,7 @@ def make_serve_step(
                   shape.seq_len, batch = shape.global_batch
     """
     model = Model(cfg)
+    mesh = None if mesh is None else auto_axes(mesh)
     rules = dict(rules or DEFAULT_RULES)
 
     def serve_step(params, cache, tokens, pos):
@@ -302,7 +304,7 @@ def make_dp_compressed_train_step(
     if cfg.family == "encdec":
         batch_spec["enc_frames"] = P(dp_axes)
 
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),

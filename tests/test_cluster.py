@@ -559,7 +559,31 @@ def test_cluster_exact_vs_scan_and_single_host(fleet, corpus, B):
     assert sum(h["rows"] for h in stats.per_host) == db.shape[0]
     assert sum(h["shards"] for h in stats.per_host) == S
     assert all(h["rpc_ms"] >= 0 for h in stats.per_host)
+    assert [h["platform"] for h in stats.per_host] == ["cpu"] * HOSTS
     assert stats.queries == B and len(stats.per_query) == B
+
+
+def test_local_worker_runs_on_the_platform_it_is_given(corpus, monkeypatch):
+    """LocalCluster sets the worker's JAX platform in the child itself:
+    a parent environment naming an accelerator this host lacks does not
+    reach the worker, which runs on the CPU and says so."""
+    p, db, db_bits = corpus
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    fl = LocalCluster(1, platform="cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    qs = pack_bits(synthetic_queries(db_bits, 4, seed=5))
+    try:
+        eng = make_engine("cluster", db, p, workers=fl.addresses,
+                          num_shards=2)
+        try:
+            ids, sims, stats = eng.knn_batch(qs, 10)
+        finally:
+            eng.close()
+    finally:
+        fl.close()
+    _check_exact(ids, sims, qs, db, 10)
+    assert fl.platform == "cpu"
+    assert [h["platform"] for h in stats.per_host] == ["cpu"]
 
 
 def test_cluster_k_exceeds_per_host_rows(fleet):

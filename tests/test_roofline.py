@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.jax_compat import cost_analysis_dict as _xla_cost_analysis
 from repro.roofline import analyze, model_flops, parse_hlo_costs
 from repro.roofline.hlo_parse import _parse_op_line, _shape_bytes
 
@@ -47,7 +46,7 @@ def test_parser_matches_cost_analysis_scanfree():
     b = jnp.zeros((256, 512), jnp.float32)
     c = jnp.zeros((512, 64), jnp.float32)
     compiled = jax.jit(f).lower(a, b, c).compile()
-    ca = _xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     costs = parse_hlo_costs(compiled.as_text())
     want_flops = 2 * 128 * 256 * 512 + 2 * 128 * 512 * 64
     assert costs.flops == pytest.approx(want_flops, rel=0.01)
@@ -70,7 +69,7 @@ def test_parser_scales_scan_bodies_by_trip_count():
     assert costs.flops == pytest.approx(want, rel=0.01)
     assert 17 in costs.trip_counts
     # XLA's own counter misses the scaling (this is WHY the parser exists)
-    ca = _xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     assert ca["flops"] < want / 2
 
 
@@ -110,8 +109,7 @@ def test_parser_counts_collectives():
         mesh = jax.make_mesh((8,), ("d",))
         def f(x):
             return jax.lax.psum(x, "d")
-        from repro.jax_compat import shard_map
-        fn = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P())
+        fn = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P())
         x = jax.ShapeDtypeStruct((800, 4), jnp.float32)
         compiled = jax.jit(fn).lower(x).compile()
         costs = parse_hlo_costs(compiled.as_text())
