@@ -5,6 +5,7 @@ from __future__ import annotations
 import csv
 import os
 import time
+from pathlib import Path
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -14,7 +15,7 @@ from repro.core import AMIHIndex, pack_bits
 from repro.data import synthetic_binary_codes, synthetic_queries
 
 # every benchmark CLI imports this module before it compiles anything
-enable_compile_cache()
+enable_compile_cache(Path(__file__).resolve().parents[1])
 
 ART_DIR = os.environ.get("REPRO_BENCH_DIR", "artifacts/bench")
 

@@ -32,7 +32,8 @@ import sys
 import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+ROOT = Path(__file__).resolve().parent
+sys.path.insert(0, str(ROOT / "src"))
 
 import numpy as np  # noqa: E402
 
@@ -201,7 +202,7 @@ def main(argv=None) -> int:
     from repro.compile_cache import enable_compile_cache
     from repro.kernels import ops
 
-    cache = Path(enable_compile_cache())
+    cache = Path(enable_compile_cache(ROOT))
 
     def cache_line(when):
         entries = len(list(cache.iterdir())) if cache.is_dir() else 0

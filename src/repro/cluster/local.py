@@ -14,11 +14,11 @@ too — fork would quietly share page-cache state the wire protocol is
 supposed to carry. Workers announce their bound ``(host, port)`` back
 over a pipe before serving.
 
-Each worker's JAX platform is set in the child's own environment
-(``JAX_PLATFORMS``, ``platform="cpu"`` by default) rather than left to
-JAX's fallback: on a host whose one chip belongs to the parent, a worker
-that asked for the accelerator would fail or hang. Workers report the
-platform they run on in their ``ready`` frame (``EngineStats.per_host``).
+Every worker runs JAX on the CPU, set in the child's own environment
+(``JAX_PLATFORMS=cpu``) rather than left to JAX's fallback: on a host
+whose one chip belongs to the parent, a worker that asked for the
+accelerator would fail or hang. Workers report the platform they run on
+in their ``ready`` frame (``EngineStats.per_host``).
 
 ``kill_worker(i)`` SIGKILLs one worker — the failure-injection hook the
 killed-worker tests use; ``close()`` terminates the fleet (idempotent).
@@ -33,12 +33,12 @@ from typing import List, Tuple
 __all__ = ["LocalCluster"]
 
 
-def _worker_main(announce, platform: str) -> None:
+def _worker_main(announce) -> None:
     # runs in the spawned interpreter, before its JAX picks a backend
-    os.environ["JAX_PLATFORMS"] = platform
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
-    jax.config.update("jax_platforms", platform)
+    jax.config.update("jax_platforms", "cpu")
     from repro.cluster.worker import serve
 
     serve(host="127.0.0.1", port=0, announce=announce)
@@ -46,13 +46,11 @@ def _worker_main(announce, platform: str) -> None:
 
 class LocalCluster:
     """``hosts`` spawned loopback workers; ``addresses[i]`` is worker
-    ``i``'s ``(host, port)``. Every worker runs JAX on ``platform``."""
+    ``i``'s ``(host, port)``. Every worker runs JAX on the CPU."""
 
-    def __init__(self, hosts: int, start_timeout: float = 120.0,
-                 platform: str = "cpu"):
+    def __init__(self, hosts: int, start_timeout: float = 120.0):
         if hosts < 1:
             raise ValueError(f"hosts must be >= 1, got {hosts}")
-        self.platform = platform
         ctx = mp.get_context("spawn")
         self.procs: List[mp.Process] = []
         self.addresses: List[Tuple[str, int]] = []
@@ -61,8 +59,7 @@ class LocalCluster:
             for _ in range(hosts):
                 parent, child = ctx.Pipe()
                 proc = ctx.Process(
-                    target=_worker_main, args=(child, platform),
-                    daemon=True,
+                    target=_worker_main, args=(child,), daemon=True
                 )
                 proc.start()
                 child.close()

@@ -28,15 +28,20 @@ def one_chip():
     from jax.experimental import topologies
 
     # a compile for a described chip cannot be read back from the
-    # persistent cache without the chip: keep it out of the cache
+    # persistent cache without the chip: keep this module's compiles out
+    # of the cache, and give later modules in this process theirs back
+    cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # pragma: no cover - depends on the install
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # pragma: no cover - depends on the install
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
 def _spec(x, sharding):
