@@ -50,6 +50,7 @@ from ..core.linear_scan import sims_for_ids
 from ..core.packing import WORD_DTYPE
 from ..core.single_table import SearchStats
 from ..obs import trace as _obs
+from ..obs.metrics import REGISTRY as _REG
 from ..pipeline.shardpool import prime_ids
 from ..shard.plan import ShardPlan
 from .transport import FrameError, recv_frame, send_frame, unpack_ragged
@@ -571,6 +572,7 @@ class ClusterEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         if k_eff == 0:

@@ -40,6 +40,7 @@ from typing import Any, ClassVar, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..obs import trace as _obs
+from ..obs.metrics import REGISTRY as _REG
 from .amih import AMIHIndex, AMIHStats
 from .enumeration import EnumerationCapExceeded
 from .linear_scan import (
@@ -389,6 +390,7 @@ class LinearScanEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         with _obs.current().span("engine.knn_batch", cat="engine",
@@ -429,27 +431,36 @@ class LinearScanEngine(SearchEngine):
 
         from ..kernels import ops
 
+        tr = _obs.current()
         if self._db_dev is None:
-            self._db_dev = jnp.asarray(self.db_words)
+            with tr.span("scan.upload_codes", cat="scan", n=self.n):
+                self._db_dev = jnp.asarray(self.db_words)
         B = q.shape[0]
         k_fetch = min(
             self.n, ops.pad_bucket(k_eff + self._topk_slack, minimum=8)
         )
-        Bp = ops.pad_bucket(B, minimum=8)
-        qp = np.zeros((Bp, q.shape[1]), dtype=q.dtype)
-        qp[:B] = q
-        _, ids32 = ops.scan_topk(
-            jnp.asarray(qp), self._db_dev, k_fetch, use_pallas=ops.on_tpu()
-        )
-        fetched = np.asarray(ids32)[:B].astype(np.int64)   # (B, k_fetch)
+        with tr.span("scan.prep", cat="scan", B=B):
+            Bp = ops.pad_bucket(B, minimum=8)
+            qp = np.zeros((Bp, q.shape[1]), dtype=q.dtype)
+            qp[:B] = q
+            qp = jnp.asarray(qp)
+        with tr.span("scan.dispatch", cat="scan", B=B, k=k_fetch):
+            _REG.counter("launches.scan_topk").add(1)
+            _, ids32 = ops.scan_topk(
+                qp, self._db_dev, k_fetch, use_pallas=ops.on_tpu()
+            )
+        with tr.span("scan.fetch", cat="scan", B=B, k=k_fetch):
+            ops.count_d2h(ids32)
+            fetched = np.asarray(ids32)[:B].astype(np.int64)  # (B, k_fetch)
         ids_out = np.empty((B, k_eff), dtype=np.int64)
         sims_out = np.empty((B, k_eff), dtype=np.float64)
-        for i in range(B):
-            cand = fetched[i]
-            sub = sims_for_ids(q[i], self.db_words, cand)  # exact float64
-            order = np.lexsort((cand, -sub))[:k_eff]
-            ids_out[i] = cand[order]
-            sims_out[i] = sub[order]
+        with tr.span("scan.rescore", cat="scan", B=B, k=k_eff):
+            for i in range(B):
+                cand = fetched[i]
+                sub = sims_for_ids(q[i], self.db_words, cand)  # exact f64
+                order = np.lexsort((cand, -sub))[:k_eff]
+                ids_out[i] = cand[order]
+                sims_out[i] = sub[order]
         return ids_out, sims_out
 
 
@@ -495,6 +506,7 @@ class SingleTableEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         with _obs.current().span("engine.knn_batch", cat="engine",
@@ -645,6 +657,7 @@ class AMIHEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         with _obs.current().span("engine.knn_batch", cat="engine",

@@ -60,6 +60,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import trace as _obs
 from .enumeration import combination_indices
 from .packing import extract_substring, popcount, substring_spans
 from .probing import probing_prefix
@@ -694,32 +695,33 @@ def dispatch_groups_device(
     from ..kernels import ops
 
     B = q_words.shape[0]
-    csr = index.device_csr
-    widths = csr["widths"]
-    stack = get_schedule_stack(
-        index.p, index.m, widths, index.probe_stream_cap
-    )
-    zs = popcount(q_words)
-    gid = np.empty(B, dtype=np.int32)
-    t_stop = np.empty(B, dtype=np.int32)
-    for z in np.unique(zs):
-        r = stack.row(int(z))
-        sel = zs == z
-        gid[sel] = r
-        sched = stack.scheds[r]
-        if stop_below is None:
-            t_stop[sel] = sched.L - 1
-        else:
-            # snapshot of the live bounds: bounds only ever rise, so a
-            # stale (lower) value is always still a valid lower bound
-            t_stop[sel] = (
-                np.searchsorted(
-                    -sched.sims64, -stop_below[sel], side="right"
-                )
-                - 1
-            ).astype(np.int32)
-    q_sub, z_sub = _query_substrings(index, q_words)
-    pow1, pow0 = _pow_arrays(q_sub, z_sub, widths, csr["wmax"])
+    with _obs.current().span("amih.prep", cat="amih", B=B):
+        csr = index.device_csr
+        widths = csr["widths"]
+        stack = get_schedule_stack(
+            index.p, index.m, widths, index.probe_stream_cap
+        )
+        zs = popcount(q_words)
+        gid = np.empty(B, dtype=np.int32)
+        t_stop = np.empty(B, dtype=np.int32)
+        for z in np.unique(zs):
+            r = stack.row(int(z))
+            sel = zs == z
+            gid[sel] = r
+            sched = stack.scheds[r]
+            if stop_below is None:
+                t_stop[sel] = sched.L - 1
+            else:
+                # snapshot of the live bounds: bounds only ever rise, so a
+                # stale (lower) value is always still a valid lower bound
+                t_stop[sel] = (
+                    np.searchsorted(
+                        -sched.sims64, -stop_below[sel], side="right"
+                    )
+                    - 1
+                ).astype(np.int32)
+        q_sub, z_sub = _query_substrings(index, q_words)
+        pow1, pow0 = _pow_arrays(q_sub, z_sub, widths, csr["wmax"])
 
     handle = ops.device_probe_walk_batched_launch(
         q_words,
@@ -764,53 +766,56 @@ def resolve_groups_device(index, pending: _PendingGroups, stats,
         # every group with ONE exhaustive verify launch — positions are
         # exact, so results are unchanged, and the batch total stays at
         # two launches
-        pm2 = ops.device_probe_scan_multi_launch(
-            np.ascontiguousarray(q_words[undone]),
-            pending.gid[undone],
-            stack=stack,
-            csr=csr,
-            p=index.p,
-            device=index.device,
-        )
+        with _obs.current().span("amih.fallback", cat="amih",
+                                 B=int(undone.size)):
+            pm2 = ops.device_probe_scan_multi_launch(
+                np.ascontiguousarray(q_words[undone]),
+                pending.gid[undone],
+                stack=stack,
+                csr=csr,
+                p=index.p,
+                device=index.device,
+            )
         posmap[undone] = pm2
         scanned[undone] = True
         index.verify_launches += 1
 
     states: List[_QueryState] = []
-    for qi in range(B):
-        sched = stack.scheds[pending.gid[qi]]
-        out_ids, out_pos, out_sims = _extract(
-            posmap[qi, :n], int(pending.t_stop[qi]), n, k, sched.sims64
-        )
-        take = out_ids.size
-        st = None if stats is None else stats[qi]
-        if st is not None:
-            _record_stats(
-                st, sched, posmap[qi, :n], out_pos, take,
-                res["probes"][qi], res["retrieved"][qi],
-                bool(scanned[qi]), rhat(int(pending.zs[qi])),
+    with _obs.current().span("amih.extract", cat="amih", B=B):
+        for qi in range(B):
+            sched = stack.scheds[pending.gid[qi]]
+            out_ids, out_pos, out_sims = _extract(
+                posmap[qi, :n], int(pending.t_stop[qi]), n, k, sched.sims64
             )
-        state = _QueryState(
-            qi=qi,
-            q_words=q_words[qi],
-            q_subs=[],
-            z_subs=[],
-            seen=np.empty(0, dtype=bool),
-            cover=[],
-            pending={},
-            out_ids=out_ids,
-            out_sims=out_sims,
-            stats=st,
-            scanned=bool(scanned[qi]),
-            done=take >= k,
-        )
-        states.append(state)
-        if on_done is not None and state.done:
-            on_done(
-                qi,
-                out_ids + index.id_offset,
-                np.asarray(out_sims, dtype=np.float64),
+            take = out_ids.size
+            st = None if stats is None else stats[qi]
+            if st is not None:
+                _record_stats(
+                    st, sched, posmap[qi, :n], out_pos, take,
+                    res["probes"][qi], res["retrieved"][qi],
+                    bool(scanned[qi]), rhat(int(pending.zs[qi])),
+                )
+            state = _QueryState(
+                qi=qi,
+                q_words=q_words[qi],
+                q_subs=[],
+                z_subs=[],
+                seen=np.empty(0, dtype=bool),
+                cover=[],
+                pending={},
+                out_ids=out_ids,
+                out_sims=out_sims,
+                stats=st,
+                scanned=bool(scanned[qi]),
+                done=take >= k,
             )
+            states.append(state)
+            if on_done is not None and state.done:
+                on_done(
+                    qi,
+                    out_ids + index.id_offset,
+                    np.asarray(out_sims, dtype=np.float64),
+                )
     return states
 
 

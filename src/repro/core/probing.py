@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import heapq
 import threading
-import warnings
 from collections import OrderedDict
 from fractions import Fraction
 from typing import Iterator, List, Optional, Tuple
@@ -43,7 +42,6 @@ __all__ = [
     "shared_probing_iter",
     "probing_cache_clear",
     "probing_cache_info",
-    "probing_cache_stats",
 ]
 
 
@@ -154,7 +152,7 @@ class _SeqEntry:
 _SEQ_CACHE: "OrderedDict[Tuple[int, int], _SeqEntry]" = OrderedDict()
 _SEQ_CACHE_MAX = 64
 _SEQ_LOCK = threading.RLock()
-# process-lifetime hit/miss counters (see probing_cache_stats): a miss is
+# process-lifetime hit/miss counters (see _cache_stats): a miss is
 # one (p, z) enumeration from scratch, so hits/(hits+misses) is the share
 # of probing-sequence work the cache absorbed
 _SEQ_HITS = 0
@@ -239,15 +237,3 @@ def _cache_stats() -> dict:
             "probing_hits": _SEQ_HITS,
             "probing_misses": _SEQ_MISSES,
         }
-
-
-def probing_cache_stats() -> dict:
-    """Deprecated alias of the internal cache-stat snapshot: new code
-    reads the ``cache.probing.*`` counters off the metrics registry (or
-    ``EngineStats.cache_info``, which engines still populate)."""
-    warnings.warn(
-        "probing_cache_stats() is deprecated; read the cache.probing.* "
-        "counters from repro.obs.metrics.REGISTRY instead",
-        DeprecationWarning, stacklevel=2,
-    )
-    return _cache_stats()

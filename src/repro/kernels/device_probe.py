@@ -45,20 +45,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.metrics import REGISTRY
 from . import ref
 from .verify_tuples import DEFAULT_BLK_C, verify_tuples_grouped
 
 POS_INF = jnp.int32(0x7FFFFFFF)
 
-# Trace-time counters (same contract as verify_tuples.TRACE_COUNTS):
-# bumped only when jax traces a new (shape, static-arg) signature, so
-# tests can assert the power-of-two padding keeps the jit cache bounded.
-TRACE_COUNTS = {
-    "device_probe_walk": 0,
-    "device_probe_scan": 0,
-    "device_probe_walk_batched": 0,
-    "device_probe_scan_multi": 0,
-}
+# Trace-time counters ``traces.<kernel>`` in the metrics registry (as in
+# verify_tuples): bumped only when jax traces a new (shape, static-arg)
+# signature, so tests can assert the power-of-two padding keeps the jit
+# cache bounded.
 
 
 def _verify(q_words, gathered, totals, *, p, cap, use_pallas, interpret):
@@ -112,7 +108,7 @@ def device_probe_walk(
     every query terminates early. Returns (posmap (B, n_pad) int32,
     probes (B,) int32, retrieved (B,) int32, done (B,) bool,
     cursor () int32, iters () int32)."""
-    TRACE_COUNTS["device_probe_walk"] += 1
+    REGISTRY.counter("traces.device_probe_walk").add(1)
     B = q_words.shape[0]
     n_pad = db_pad.shape[0]
     V = offsets.shape[1]
@@ -276,7 +272,7 @@ def device_probe_scan(
     one launch (``lax.map`` over row chunks keeps peak memory at
     (B, chunk, W)). Returns (B, n_pad) int32 exact walk positions —
     the fused form of the host enumeration-cap scan fallback."""
-    TRACE_COUNTS["device_probe_scan"] += 1
+    REGISTRY.counter("traces.device_probe_scan").add(1)
     B, W = q_words.shape
     n_pad = db_pad.shape[0]
     assert n_pad % chunk == 0, (n_pad, chunk)
@@ -356,7 +352,7 @@ def device_probe_walk_batched(
     fall through to the fused multi-group scan. Returns (posmap
     (B, n_pad) int32, probes (B,) int32, retrieved (B,) int32, done
     (B,) bool, cursor (G,) int32, iters () int32)."""
-    TRACE_COUNTS["device_probe_walk_batched"] += 1
+    REGISTRY.counter("traces.device_probe_walk_batched").add(1)
     B = q_words.shape[0]
     G = g_start.shape[0]
     n_pad = db_pad.shape[0]
@@ -547,7 +543,7 @@ def device_probe_scan_multi(
     a per-query ``gid`` row into the stacked inverse-position tables, so
     ONE launch finishes the bailed queries of EVERY group in the batch.
     Returns (B, n_pad) int32 exact walk positions."""
-    TRACE_COUNTS["device_probe_scan_multi"] += 1
+    REGISTRY.counter("traces.device_probe_scan_multi").add(1)
     B, W = q_words.shape
     n_pad = db_pad.shape[0]
     pp2 = inv_pos.shape[1]

@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import warnings
-from collections.abc import Mapping
 from typing import Tuple
 
 import jax
@@ -28,10 +26,9 @@ from .verify_tuples import verify_tuples as _verify_tuples_kernel
 from .verify_tuples import verify_tuples_grouped as _verify_grouped_kernel
 
 __all__ = [
-    "LAUNCH_COUNTS",
-    "LAUNCH_COUNTS_BY_DEVICE",
     "PendingKeys",
     "PendingWalk",
+    "count_d2h",
     "device_key",
     "device_probe_scan_launch",
     "device_probe_scan_multi_launch",
@@ -48,51 +45,18 @@ __all__ = [
 ]
 
 # Host-side launch accounting: bumped once per device dispatch of each op,
-# into the process metrics registry under ``launches.<op>``.
+# into the process metrics registry under ``launches.<op>`` (and, for a
+# placed launch, ``launches.device.<dkey>``).
 # AMIH's batched verification asserts exactly one grouped launch per
 # (z-group, tuple-step) through this counter (see tests/test_verify_grouped);
 # the device probe path asserts O(1) launches per z-group through
 # "device_probe" (the fused walk) and "device_probe_scan" (the at-most-one
 # exhaustive fallback for truncated schedules).
-_LAUNCH_KEYS = ("verify_grouped", "verify", "device_probe",
-                "device_probe_scan")
 
-
-class _DeprecatedLaunchCounts(Mapping):
-    """The old ``ops.LAUNCH_COUNTS`` dict surface, now a read-only view
-    of the ``launches.*`` registry counters. Direct reads warn — new
-    code reads ``repro.obs.metrics.REGISTRY.value("launches.<op>")``."""
-
-    def __getitem__(self, key: str) -> int:
-        warnings.warn(
-            "ops.LAUNCH_COUNTS is deprecated; read "
-            "repro.obs.metrics.REGISTRY.value('launches.<op>') instead",
-            DeprecationWarning, stacklevel=2,
-        )
-        if key not in _LAUNCH_KEYS:
-            raise KeyError(key)
-        return _REG.value("launches." + key)
-
-    def __iter__(self):
-        return iter(_LAUNCH_KEYS)
-
-    def __len__(self) -> int:
-        return len(_LAUNCH_KEYS)
-
-
-LAUNCH_COUNTS = _DeprecatedLaunchCounts()
-
-# Per-device split of the grouped-verify launches: device key -> count.
-# The mesh-resident sharded AMIH path places each shard's verification on
-# that shard's assigned device; tests assert the placement actually
-# happened (not just that the arrays were tagged) through this counter.
-# Mirrored into the registry as ``launches.device.<dkey>``.
-LAUNCH_COUNTS_BY_DEVICE: dict = {}
-
-# Guards the counter bumps: thread-mode shard probing (forced for the
-# pallas verify backend) dispatches launches from several threads, and
-# dict get+store is not atomic — an unguarded bump could drop counts the
-# placement tests assert on.
+# Guards the per-device jit instances and the position-map pool:
+# thread-mode shard probing (forced for the pallas verify backend)
+# dispatches launches from several threads, and dict check-then-insert
+# is not atomic.
 _LAUNCH_LOCK = threading.Lock()
 
 
@@ -102,10 +66,15 @@ def _bump_launch(op: str, dkey: "str | None" = None) -> None:
     _REG.counter("launches." + op).add(1)
     if dkey is not None:
         _REG.counter("launches.device." + dkey).add(1)
-        with _LAUNCH_LOCK:
-            LAUNCH_COUNTS_BY_DEVICE[dkey] = (
-                LAUNCH_COUNTS_BY_DEVICE.get(dkey, 0) + 1
-            )
+
+
+def count_d2h(*arrays) -> None:
+    """Count the bytes of the device arrays among ``arrays`` that the
+    host is about to copy back (``d2h.bytes``): every served path's
+    device-to-host fetch calls this beside its ``np.asarray``."""
+    _REG.counter("d2h.bytes").add(
+        sum(int(a.nbytes) for a in arrays if isinstance(a, jax.Array))
+    )
 
 
 def device_key(device) -> str:
@@ -438,14 +407,10 @@ class PendingKeys:
         self._dkey = dkey
 
     def get(self) -> np.ndarray:
-        tr = _obs.current()
-        if not tr.enabled:
+        with _obs.current().span("launch.verify_grouped.resolve",
+                                 cat="kernel", device=self._dkey):
+            count_d2h(self._keys)
             return np.asarray(self._keys)[: self._B, : self._C]
-        t0 = _obs.now_us()
-        out = np.asarray(self._keys)[: self._B, : self._C]
-        tr.record("launch.verify_grouped.resolve", t0, _obs.now_us(),
-                  cat="kernel", device=self._dkey)
-        return out
 
 
 def verify_tuples_grouped_launch(
@@ -469,7 +434,7 @@ def verify_tuples_grouped_launch(
     runs there — ``db_words`` is expected to already be resident on the
     same device (``AMIHIndex.db_dev`` uploads it once at build). Each
     device gets its own jit instance (``_gather_verify_grouped_for``) and
-    its own entry in ``LAUNCH_COUNTS_BY_DEVICE``; ``device=None`` keeps
+    its own ``launches.device.<dkey>`` counter; ``device=None`` keeps
     the old default-device behavior."""
     idx = np.ascontiguousarray(np.asarray(cand_idx, dtype=np.int32))
     lens = np.asarray(lengths, dtype=np.int32)
@@ -634,6 +599,7 @@ def device_probe_walk_launch(
                 interpret=not on_tpu(),
             )
         )
+        count_d2h(posmap, probes, retrieved, done, cursor, iters)
         return {
             "posmap": np.asarray(posmap)[:B],
             "probes": np.asarray(probes)[:B],
@@ -685,6 +651,7 @@ def device_probe_scan_launch(
             use_pallas=use_pallas,
             interpret=not on_tpu(),
         )
+        count_d2h(pm)
         return np.asarray(pm)[:B]
 
 
@@ -739,23 +706,21 @@ class PendingWalk:
 
     def get(self) -> dict:
         if self._res is None:
-            tr = _obs.current()
-            t0 = _obs.now_us() if tr.enabled else 0.0
-            posmap, probes, retrieved, done, cursor, iters = self._out
-            self._res = {
-                "posmap": np.array(posmap)[: self._B],
-                "probes": np.asarray(probes)[: self._B],
-                "retrieved": np.asarray(retrieved)[: self._B],
-                "done": np.asarray(done)[: self._B],
-                "cursor": np.asarray(cursor),
-                "iters": int(iters),
-            }
-            _recycle_posmap(self._pool_key, posmap)
-            self._out = None
-            if tr.enabled:
-                tr.record("launch.device_probe.resolve", t0,
-                          _obs.now_us(), cat="kernel",
-                          device=self._pool_key[0])
+            with _obs.current().span("launch.device_probe.resolve",
+                                     cat="kernel",
+                                     device=self._pool_key[0]):
+                count_d2h(*self._out)
+                posmap, probes, retrieved, done, cursor, iters = self._out
+                self._res = {
+                    "posmap": np.array(posmap)[: self._B],
+                    "probes": np.asarray(probes)[: self._B],
+                    "retrieved": np.asarray(retrieved)[: self._B],
+                    "done": np.asarray(done)[: self._B],
+                    "cursor": np.asarray(cursor),
+                    "iters": int(iters),
+                }
+                _recycle_posmap(self._pool_key, posmap)
+                self._out = None
         return self._res
 
 
@@ -863,35 +828,32 @@ def device_probe_walk_batched_launch(
             donate_argnames=("posmap_in",),
         ),
     )
-    _tr = _obs.current()
-    _t0 = _obs.now_us() if _tr.enabled else 0.0
-    out = fn(
-        posmap_in,
-        *per_call,
-        bundle["g_start"],
-        bundle["g_end"],
-        bundle["tbl"],
-        bundle["step"],
-        bundle["idx1"],
-        bundle["idx0"],
-        bundle["maxi1"],
-        bundle["maxi0"],
-        bundle["widths"],
-        csr["offsets"],
-        csr["ids"],
-        csr["db_pad"],
-        bundle["inv_pos"],
-        p=p,
-        tile=tile,
-        cap=cap,
-        kmax=KMAX,
-        check_every=check_every,
-        use_pallas=use_pallas,
-        interpret=not on_tpu(),
-    )
-    if _tr.enabled:
-        _tr.record("launch.device_probe.dispatch", _t0, _obs.now_us(),
-                   cat="kernel", device=dkey, B=B)
+    with _obs.current().span("launch.device_probe.dispatch", cat="kernel",
+                             device=dkey, B=B):
+        out = fn(
+            posmap_in,
+            *per_call,
+            bundle["g_start"],
+            bundle["g_end"],
+            bundle["tbl"],
+            bundle["step"],
+            bundle["idx1"],
+            bundle["idx0"],
+            bundle["maxi1"],
+            bundle["maxi0"],
+            bundle["widths"],
+            csr["offsets"],
+            csr["ids"],
+            csr["db_pad"],
+            bundle["inv_pos"],
+            p=p,
+            tile=tile,
+            cap=cap,
+            kmax=KMAX,
+            check_every=check_every,
+            use_pallas=use_pallas,
+            interpret=not on_tpu(),
+        )
     pending = PendingWalk(out, B, pool_key)
     return pending.get() if blocking else pending
 
@@ -949,6 +911,7 @@ def device_probe_scan_multi_launch(
             use_pallas=use_pallas,
             interpret=not on_tpu(),
         )
+        count_d2h(pm)
         return np.asarray(pm)[:B]
 
 

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..obs.metrics import REGISTRY
 from .ref import popcount32
 
 DEFAULT_BLK_N = 1024
@@ -48,11 +49,11 @@ DEFAULT_BLK_C = 128
 # query rows per grouped-verify program: one sublane tile
 DEFAULT_BLK_B = 8
 
-# Trace-time counters, keyed by kernel name: the jitted wrappers bump them
-# from their Python bodies, which only execute when jax actually traces a
-# new (shape, static-arg) signature. Tests assert the jit cache stays
-# bounded under the power-of-two padding buckets (see ops.pad_bucket).
-TRACE_COUNTS = {"verify_tuples": 0, "verify_tuples_grouped": 0}
+# Trace-time counters ``traces.<kernel>`` in the metrics registry: the
+# jitted wrappers bump them from their Python bodies, which only execute
+# when jax actually traces a new (shape, static-arg) signature. Tests
+# assert the jit cache stays bounded under the power-of-two padding
+# buckets (see ops.pad_bucket).
 
 
 def _verify_kernel(q_ref, cand_ref, r10_ref, r01_ref, *, n_words: int):
@@ -112,7 +113,7 @@ def verify_tuples_grouped(
     when ``c < lengths[i]``, and -1 (masked padding) otherwise.
     C % blk_c == 0.
     """
-    TRACE_COUNTS["verify_tuples_grouped"] += 1
+    REGISTRY.counter("traces.verify_tuples_grouped").add(1)
     B, W = q_words.shape
     Bc, C, Wd = cand_words.shape
     assert W == Wd and B == Bc and B == lengths.shape[0]
@@ -148,7 +149,7 @@ def verify_tuples(
     interpret: bool,
 ):
     """(W,), (N, W) -> (r10, r01), each (N,) int32. N % blk_n == 0."""
-    TRACE_COUNTS["verify_tuples"] += 1
+    REGISTRY.counter("traces.verify_tuples").add(1)
     (W,) = q_words.shape
     N, Wd = cand_words.shape
     assert W == Wd

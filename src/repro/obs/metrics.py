@@ -1,11 +1,12 @@
 """Process-wide metrics registry: counters + bounded histograms.
 
-One named surface replaces the scattered ad-hoc counters that grew up
-with the stack: ``ops.LAUNCH_COUNTS`` bumps land here under
-``launches.*``, the probing/schedule cache hit rates under ``cache.*``,
-and the serving tier's rolling latency window under ``serve.*`` (the
-``LatencyTracker`` in ``pipeline/stream.py`` is now a thin wrapper over
-``Histogram``). Pure stdlib — percentiles are nearest-rank over a
+One named surface for every counter of the stack: kernel launches
+under ``launches.*`` (per device under ``launches.device.*``), jit
+traces of the kernels under ``traces.*``, device-to-host bytes under
+``d2h.bytes``, batches served under ``engine.batches``, the
+probing/schedule cache hit rates under ``cache.*``, and the serving
+tier's rolling latency window under ``serve.*`` (the ``LatencyTracker``
+in ``pipeline/stream.py`` is a thin wrapper over ``Histogram``). Pure stdlib — percentiles are nearest-rank over a
 bounded sample window, no numpy.
 
 Thread safety: every mutation takes the instrument's own lock; the

@@ -15,7 +15,17 @@ tracer once (``current()``) and checks ``.enabled`` — a single
 attribute read — before touching the clock. The inner AMIH loop uses
 explicit ``if tr.enabled:`` guards around ``now_us()``/``record()``;
 colder sites use the ``span()`` context manager, which returns a
-shared no-op object when tracing is off.
+shared no-op object when tracing is off and no profiler records.
+
+The profiler's clock: while a JAX profiler session records
+(``jax.profiler.start_trace``), ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name and args, whether or
+not this tracer is enabled and whatever its sampling decided, so the
+program's spans land in the profiler trace on one clock with the
+device's operations. The check is one ``TraceAnnotation.is_enabled()``
+call, made only once ``jax`` is in ``sys.modules``: this package never
+imports jax itself. ``record()`` takes explicit timestamps after the
+fact and never reaches the profiler.
 
 Sampling: ``sample`` is a probability applied when a TOP-LEVEL span
 opens on a thread; the decision is inherited by every nested span, so
@@ -29,6 +39,7 @@ from __future__ import annotations
 
 import os
 import random
+import sys
 import threading
 import time
 import uuid
@@ -69,29 +80,54 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+# jax.profiler.TraceAnnotation, bound on the first span after jax is
+# imported; None before then (and forever in a process without jax)
+_ANNOTATION = None
+
+
+def _profiler_annotation():
+    """``TraceAnnotation`` while a JAX profiler session records, else
+    None. Never imports jax into a process that has not."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        ann = _ANNOTATION = TraceAnnotation
+    return ann if ann.is_enabled() else None
+
 
 class _SpanCtx:
     """One live span; append-on-exit so children land before parents
     only by end time (Perfetto nests by interval containment)."""
 
-    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_keep", "_depth")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_keep", "_depth",
+                 "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]], keep: bool, depth: int):
+                 args: Optional[Dict[str, Any]], keep: bool, depth: int,
+                 ann=None):
         self._tr = tr
         self.name = name
         self.cat = cat
         self.args = args
         self._keep = keep
         self._depth = depth
+        self._ann = ann
         self._t0 = 0.0
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = now_us()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tls = self._tr._tls
         tls.stack.pop()
         if self._keep:
@@ -126,10 +162,15 @@ class Tracer:
     # ------------------------------------------------------------ spans
     def span(self, name: str, cat: str = "span",
              **args: Any):
-        """Context manager for a nested span. No-op when disabled or
-        when the enclosing top-level span was sampled out."""
+        """Context manager for a nested span. Recorded into the buffer
+        unless disabled or the enclosing top-level span was sampled
+        out; also a profiler ``TraceAnnotation`` while a JAX profiler
+        session records. ``NOOP_SPAN`` when neither."""
+        ann = _profiler_annotation()
+        if ann is not None:
+            ann = ann(name, **args)
         if not self.enabled:
-            return NOOP_SPAN
+            return NOOP_SPAN if ann is None else ann
         tls = self._tls
         stack = getattr(tls, "stack", None)
         if stack is None:
@@ -142,12 +183,13 @@ class Tracer:
             keep = self._rng.random() < self.sample
         stack.append(keep)
         return _SpanCtx(self, name, cat, args or None, keep,
-                        len(stack) - 1)
+                        len(stack) - 1, ann)
 
     def record(self, name: str, t0_us: float, t1_us: float,
                cat: str = "span", **args: Any) -> None:
-        """Append a completed span from explicit timestamps (dispatch →
-        resolve pairs measure their endpoints manually)."""
+        """Append a completed span from explicit timestamps (the inner
+        AMIH loop's guarded sites). Never reaches a profiler trace:
+        a site that should appear there uses ``span()``."""
         if not self.enabled:
             return
         span = {

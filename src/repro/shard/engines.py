@@ -51,6 +51,8 @@ from ..core.engine import (
 from ..core.linear_scan import sims_for_ids
 from ..core.packing import WORD_DTYPE
 from ..core.single_table import SearchStats
+from ..obs import trace as _obs
+from ..obs.metrics import REGISTRY as _REG
 from .plan import ShardPlan
 
 __all__ = ["ShardedAMIHEngine", "ShardedScanEngine"]
@@ -150,6 +152,7 @@ class ShardedScanEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         if k_eff == 0:
@@ -174,14 +177,15 @@ class ShardedScanEngine(SearchEngine):
         sims_out = np.empty((B, k_eff), dtype=np.float64)
         cand_total = 0
         shard_counts = np.zeros(self.plan.num_shards, dtype=np.int64)
-        for i in range(B):
-            cand = pool_gids[i][pool_gids[i] >= 0].astype(np.int64)
-            cand_total += cand.size
-            shard_counts += np.asarray(_count_per_shard(self.plan, cand))
-            sub = sims_for_ids(q[i], self.db_words, cand)  # exact float64
-            order = np.lexsort((cand, -sub))[:k_eff]
-            ids_out[i] = cand[order]
-            sims_out[i] = sub[order]
+        with _obs.current().span("shard.merge", cat="shard", B=B):
+            for i in range(B):
+                cand = pool_gids[i][pool_gids[i] >= 0].astype(np.int64)
+                cand_total += cand.size
+                shard_counts += np.asarray(_count_per_shard(self.plan, cand))
+                sub = sims_for_ids(q[i], self.db_words, cand)  # exact f64
+                order = np.lexsort((cand, -sub))[:k_eff]
+                ids_out[i] = cand[order]
+                sims_out[i] = sub[order]
         self.shard_launches += self.plan.num_shards
         per_shard = [
             {
@@ -224,6 +228,7 @@ class ShardedScanEngine(SearchEngine):
             self.mesh, jnp.asarray(qp), self._db_dev, self.plan, k_fetch,
             chunk=self.chunk,
         )
+        ops.count_d2h(sims, gids)
         return np.asarray(sims)[:B], np.asarray(gids)[:B]
 
     # ------------------------------------------------------------ host mode
@@ -256,10 +261,12 @@ class ShardedScanEngine(SearchEngine):
             count = self.plan.counts[s]
             if count == 0:
                 continue
+            _REG.counter("launches.scan_topk").add(1)
             sims, ids = ops.scan_topk(
                 qj, self._shard_dev[s], min(k_fetch, count),
                 chunk=self.chunk, use_pallas=ops.on_tpu(),
             )
+            ops.count_d2h(sims, ids)
             sims = np.asarray(sims)[:B]
             gids = np.asarray(ids)[:B].astype(np.int64)
             gids = np.where(sims > -np.inf, gids + self.plan.starts[s], -1)
@@ -284,7 +291,7 @@ class ShardedAMIHEngine(SearchEngine):
     bandwidth scale with the shard count instead of serializing through
     device 0. Only the O(K) per-shard result lists ever cross back to
     the host merge. ``stats.per_shard[s]["device"]`` records where each
-    shard's work landed (``kernels.ops.LAUNCH_COUNTS_BY_DEVICE`` counts
+    shard's work landed (the registry's ``launches.device.<dkey>`` counts
     the launches per device).
 
     ``probe_workers`` switches shard probing from the sequential chain to
@@ -453,6 +460,7 @@ class ShardedAMIHEngine(SearchEngine):
 
     def knn_batch(self, q_words, k):
         q = self._check_queries(q_words, self.p)
+        _REG.counter("engine.batches").add(1)
         B = q.shape[0]
         k_eff = min(k, self.n)
         per_query = [AMIHStats() for _ in range(B)]
@@ -477,14 +485,15 @@ class ShardedAMIHEngine(SearchEngine):
         )
         ids_out = np.empty((B, k_eff), dtype=np.int64)
         sims_out = np.empty((B, k_eff), dtype=np.float64)
-        for i in range(B):
-            gids = np.concatenate(gid_parts[i]) if gid_parts[i] \
-                else np.empty(0, dtype=np.int64)
-            sims = np.concatenate(sim_parts[i]) if sim_parts[i] \
-                else np.empty(0, dtype=np.float64)
-            order = np.lexsort((gids, -sims))[:k_eff]
-            ids_out[i] = gids[order]
-            sims_out[i] = sims[order]
+        with _obs.current().span("shard.merge", cat="shard", B=B):
+            for i in range(B):
+                gids = np.concatenate(gid_parts[i]) if gid_parts[i] \
+                    else np.empty(0, dtype=np.int64)
+                sims = np.concatenate(sim_parts[i]) if sim_parts[i] \
+                    else np.empty(0, dtype=np.float64)
+                order = np.lexsort((gids, -sims))[:k_eff]
+                ids_out[i] = gids[order]
+                sims_out[i] = sims[order]
         stats = EngineStats(
             backend=self.name, queries=B, per_query=per_query,
             shards=self.plan.num_shards, per_shard=per_shard,
@@ -546,20 +555,21 @@ class ShardedAMIHEngine(SearchEngine):
             shard_out, fuse_meta, per_query, B, k_eff
         )
         results: List[Tuple[np.ndarray, np.ndarray]] = []
-        for i in range(B):
-            gids = np.concatenate(gid_parts[i]) if gid_parts[i] \
-                else np.empty(0, dtype=np.int64)
-            sims = np.concatenate(sim_parts[i]) if sim_parts[i] \
-                else np.empty(0, dtype=np.float64)
-            order = np.lexsort((gids, -sims))[:k_eff]
-            ids_i, sims_i = gids[order], sims[order]
-            results.append((ids_i, sims_i))
-            if sims_i.size >= k_eff:
-                kth = float(sims_i[-1])
-                if kth > floor[i]:
-                    floor[i] = kth
-                if on_done is not None:
-                    on_done(i, ids_i, sims_i)
+        with _obs.current().span("shard.merge", cat="shard", B=B):
+            for i in range(B):
+                gids = np.concatenate(gid_parts[i]) if gid_parts[i] \
+                    else np.empty(0, dtype=np.int64)
+                sims = np.concatenate(sim_parts[i]) if sim_parts[i] \
+                    else np.empty(0, dtype=np.float64)
+                order = np.lexsort((gids, -sims))[:k_eff]
+                ids_i, sims_i = gids[order], sims[order]
+                results.append((ids_i, sims_i))
+                if sims_i.size >= k_eff:
+                    kth = float(sims_i[-1])
+                    if kth > floor[i]:
+                        floor[i] = kth
+                    if on_done is not None:
+                        on_done(i, ids_i, sims_i)
         stats = EngineStats(
             backend=self.name, queries=B, per_query=per_query,
             shards=self.plan.num_shards, per_shard=per_shard,

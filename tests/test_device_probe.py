@@ -185,8 +185,6 @@ def test_batched_trace_counts_bounded():
     kernels: the schedule stack pads its group count and stream length
     to power-of-two buckets, so once a set of z values is resident, any
     mix of them traces nothing new."""
-    from repro.kernels import device_probe
-
     p, n, k = 64, 800, 5
     db, _ = _make_data(n, p, 1, seed=23)
     dev = AMIHIndex.build(db, p, probe_backend="device")
@@ -202,7 +200,7 @@ def test_batched_trace_counts_bounded():
     # warmup: every z of the support enters the stack; this call pays
     # the trace (and any stack growth / commit)
     dev.knn_batch(batch_with_zs(support + support[:3]), k)
-    before = dict(device_probe.TRACE_COUNTS)
+    before = _REG.values("traces.")
     for seed in range(5):
         r = np.random.default_rng(100 + seed)
         # a different histogram over the SAME support each batch
@@ -210,13 +208,13 @@ def test_batched_trace_counts_bounded():
             [0.4, 0.3, 0.15, 0.1, 0.05], seed
         ))
         dev.knn_batch(batch_with_zs(zs), k)
-    after = dict(device_probe.TRACE_COUNTS)
-    assert after["device_probe_walk_batched"] == \
-        before["device_probe_walk_batched"]
+    after = _REG.values("traces.")
+    walk = "traces.device_probe_walk_batched"
+    assert after.get(walk, 0) == before.get(walk, 0)
     # the scan fallback pads the BAILED subset to a power-of-two bucket,
     # so at most log2(B) distinct shapes can ever trace
-    assert after["device_probe_scan_multi"] - \
-        before["device_probe_scan_multi"] <= 3
+    scan = "traces.device_probe_scan_multi"
+    assert after.get(scan, 0) - before.get(scan, 0) <= 3
 
 
 def test_schedule_cache_shared_across_indexes():

@@ -13,15 +13,12 @@ Covers the observability tentpole's contracts:
     host/stage floors with documented exit codes.
   - AMRP frames without the optional ``trace`` meta still parse
     (backward compatibility), and frames with it round-trip.
-  - The deprecated counter surfaces (ops.LAUNCH_COUNTS,
-    probing_cache_stats) warn once per read and mirror the registry.
 """
 
 import json
 import socket
 import threading
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -488,32 +485,3 @@ def test_frames_with_trace_meta_roundtrip():
     assert meta["spans"] == spans
     kind, meta, _ = _frame_roundtrip("pong", {"seq": 7, "ts": 123.5})
     assert meta["ts"] == 123.5
-
-
-# ------------------------------------------------------ deprecated aliases
-def test_launch_counts_alias_warns_and_mirrors_registry():
-    from repro.kernels import ops
-    from repro.obs.metrics import REGISTRY
-
-    with pytest.warns(DeprecationWarning, match="LAUNCH_COUNTS"):
-        before = ops.LAUNCH_COUNTS["verify"]
-    assert before == REGISTRY.value("launches.verify")
-    assert set(ops.LAUNCH_COUNTS) == {
-        "verify_grouped", "verify", "device_probe", "device_probe_scan",
-    }
-    assert len(ops.LAUNCH_COUNTS) == 4
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(KeyError):
-            ops.LAUNCH_COUNTS["nonsense"]
-
-
-def test_probing_cache_stats_warns_and_matches_internal():
-    from repro.core import probing
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        internal = probing._cache_stats()        # new surface: no warning
-    with pytest.warns(DeprecationWarning, match="probing_cache_stats"):
-        legacy = probing.probing_cache_stats()
-    assert legacy == internal
-    assert {"probing_hits", "probing_misses"} <= set(legacy)

@@ -307,6 +307,7 @@ def test_sharded_amih_verify_runs_on_assigned_devices_mesh():
         from repro.data import synthetic_binary_codes, synthetic_queries
         from repro.kernels import ops
         from repro.launch.mesh import make_mesh
+        from repro.obs.metrics import REGISTRY as _REG
 
         p, n, B, k = 64, 1499, 8, 7
         db_bits = synthetic_binary_codes(n, p, seed=0)
@@ -320,13 +321,13 @@ def test_sharded_amih_verify_runs_on_assigned_devices_mesh():
         for s, ix in eng.indexes:
             (got,) = ix.db_dev.devices()
             assert got == eng.plan.device_for(s), (s, got)
-        before = dict(ops.LAUNCH_COUNTS_BY_DEVICE)
+        before = _REG.values("launches.device.")
         ids, sims, st = eng.knn_batch(qs, k)
         for i in range(B):
             _, sims_l = linear_scan_knn(qs[i], db, k)
             np.testing.assert_array_equal(sims[i], sims_l)
-        delta = {d: c - before.get(d, 0)
-                 for d, c in ops.LAUNCH_COUNTS_BY_DEVICE.items()}
+        delta = {name[len("launches.device."):]: c - before.get(name, 0)
+                 for name, c in _REG.values("launches.device.").items()}
         active = {d for d, c in delta.items() if c > 0}
         assert len(active) == 8 and "default" not in active, delta
         # stats record the placement and the per-shard launch counts
@@ -379,7 +380,6 @@ def test_sharded_amih_fused_one_launch_per_device():
     _run("""
         from repro.core import make_engine, linear_scan_knn, pack_bits
         from repro.data import synthetic_binary_codes, synthetic_queries
-        from repro.kernels import ops
         from repro.obs.metrics import REGISTRY as _REG
 
         p, n, B, k = 64, 4000, 16, 5
@@ -389,13 +389,13 @@ def test_sharded_amih_fused_one_launch_per_device():
         eng = make_engine("sharded_amih", db, p, num_shards=16,
                           probe_backend="device")
         assert len({str(d) for d in eng.plan.devices}) == 8
-        before = dict(ops.LAUNCH_COUNTS_BY_DEVICE)
+        before = _REG.values("launches.device.")
         walk0 = _REG.value("launches.device_probe")
         ids, sims, st = eng.knn_batch(qs, k)
         # ONE fused walk launch per device, not one per shard
         assert _REG.value("launches.device_probe") - walk0 == 8
-        delta = {d: c - before.get(d, 0)
-                 for d, c in ops.LAUNCH_COUNTS_BY_DEVICE.items()}
+        delta = {name[len("launches.device."):]: c - before.get(name, 0)
+                 for name, c in _REG.values("launches.device.").items()}
         active = {d for d, c in delta.items() if c > 0}
         assert len(active) == 8 and "default" not in active, delta
         # walk (+ at most one scan-fallback) per device

@@ -71,6 +71,28 @@ def test_grouped_ref_path_matches_pallas():
     np.testing.assert_array_equal(k_pl, k_ref)
 
 
+def test_resolve_counts_the_padded_keys_copied_to_the_host():
+    """``PendingKeys.get`` counts the whole padded (B_pad, C_pad) int32
+    key block it copies back in ``d2h.bytes``; the empty launch, which
+    never reaches the device, counts nothing."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(5)
+    db, qs, idx, lengths = _random_workload(rng, 5, 20, 64)
+    before = _REG.value("d2h.bytes")
+    keys = ops.verify_tuples_grouped_op(
+        qs, jnp.asarray(db), idx, lengths, p=64, use_pallas=False
+    )
+    assert keys.shape == (5, 20)
+    Bp, Cp = ops.pad_bucket(5, minimum=1), ops.pad_bucket(20, minimum=8)
+    assert _REG.value("d2h.bytes") - before == Bp * Cp * 4
+    before = _REG.value("d2h.bytes")
+    ops.verify_tuples_grouped_op(
+        qs, jnp.asarray(db), idx[:, :0], lengths, p=64
+    )
+    assert _REG.value("d2h.bytes") == before
+
+
 def test_empty_candidate_matrix():
     import jax.numpy as jnp
 
@@ -91,18 +113,13 @@ def test_jit_cache_stays_bounded_across_varied_shapes():
     by at most log2-many entries, not one per shape."""
     import jax.numpy as jnp
 
-    # the package re-exports the kernel *function* under this name (which
-    # shadows the submodule attribute), so resolve the module itself for
-    # its trace counters
-    import importlib
-
-    vt = importlib.import_module("repro.kernels.verify_tuples")
+    from repro.obs.metrics import REGISTRY
 
     rng = np.random.default_rng(11)
     p = 64
     db = pack_bits((rng.random((256, p)) < 0.5).astype(np.uint8))
     db_dev = jnp.asarray(db)
-    before = vt.TRACE_COUNTS["verify_tuples_grouped"]
+    before = REGISTRY.value("traces.verify_tuples_grouped")
     shapes = [(1 + (i % 13), 1 + 2 * i) for i in range(100)]
     assert len(set(shapes)) == 100
     for B, C in shapes:
@@ -112,7 +129,7 @@ def test_jit_cache_stays_bounded_across_varied_shapes():
         ops.verify_tuples_grouped_op(
             qs, db_dev, idx, lengths, p=p, use_pallas=True
         )
-    traces = vt.TRACE_COUNTS["verify_tuples_grouped"] - before
+    traces = REGISTRY.value("traces.verify_tuples_grouped") - before
     # B buckets {1,2,4,8,16} x C buckets {8,16,32,64,128,256} at most
     assert traces <= 30, traces
 
