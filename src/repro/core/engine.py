@@ -444,8 +444,12 @@ class LinearScanEngine(SearchEngine):
             qp = np.zeros((Bp, q.shape[1]), dtype=q.dtype)
             qp[:B] = q
             qp = jnp.asarray(qp)
-        with tr.span("scan.dispatch", cat="scan", B=B, k=k_fetch):
+        group = ops.topk_group_width(self.n, k_fetch)
+        with tr.span("scan.dispatch", cat="scan", B=B, k=k_fetch,
+                     group=group):
             _REG.counter("launches.scan_topk").add(1)
+            if group:
+                _REG.counter("launches.scan_topk.two_stage").add(1)
             _, ids32 = ops.scan_topk(
                 qp, self._db_dev, k_fetch, use_pallas=ops.on_tpu()
             )

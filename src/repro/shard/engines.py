@@ -262,6 +262,8 @@ class ShardedScanEngine(SearchEngine):
             if count == 0:
                 continue
             _REG.counter("launches.scan_topk").add(1)
+            if ops.topk_group_width(count, k_fetch, self.chunk):
+                _REG.counter("launches.scan_topk.two_stage").add(1)
             sims, ids = ops.scan_topk(
                 qj, self._shard_dev[s], min(k_fetch, count),
                 chunk=self.chunk, use_pallas=ops.on_tpu(),

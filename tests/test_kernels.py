@@ -96,6 +96,120 @@ def test_scan_topk_streaming_exact(rng, chunk, k):
         )
 
 
+def _one_stage_merge(best_sims, best_ids, sims, first_id, k):
+    """The running top-K merge as one ``lax.top_k`` over the running best
+    and the whole chunk: the oracle of the two-stage merge."""
+    import jax
+
+    ids = jnp.broadcast_to(
+        (first_id + jnp.arange(sims.shape[1], dtype=jnp.int32))[None, :],
+        sims.shape,
+    )
+    all_sims = jnp.concatenate([best_sims, sims], axis=1)
+    all_ids = jnp.concatenate([best_ids, ids], axis=1)
+    new_sims, pos = jax.lax.top_k(all_sims, k)
+    return new_sims, jnp.take_along_axis(all_ids, pos, axis=1)
+
+
+def _one_stage_scan_topk(q, db, k, chunk, use_pallas, n_valid=None):
+    """``scan_topk`` with the one-stage merge in every step."""
+    import jax
+
+    B, W = q.shape
+    N = db.shape[0]
+    k, chunk = min(k, N), min(chunk, N)
+    n_chunks = -(-N // chunk)
+    dbp = jnp.pad(db, ((0, n_chunks * chunk - N), (0, 0)))
+    row_ids = jnp.arange(n_chunks * chunk).reshape(n_chunks, chunk)
+    valid = row_ids < N
+    if n_valid is not None:
+        valid = valid & (row_ids < n_valid)
+
+    def step(carry, inp):
+        db_chunk, ok, j = inp
+        sims = ops.scan_scores(q, db_chunk, use_pallas=use_pallas)
+        sims = jnp.where(ok[None, :], sims, -jnp.inf)
+        return _one_stage_merge(*carry, sims, j * chunk, k), None
+
+    init = (jnp.full((B, k), -jnp.inf, jnp.float32),
+            jnp.full((B, k), -1, jnp.int32))
+    (sims, ids), _ = jax.lax.scan(
+        step, init,
+        (dbp.reshape(n_chunks, chunk, W), valid,
+         jnp.arange(n_chunks, dtype=jnp.int32)),
+    )
+    return sims, ids
+
+
+def _weight16_codes(rng, n, levels):
+    """64-bit codes with 16 bits set, drawn from ``levels`` fixed
+    patterns: Eq. 3 scores are then |q & b| / 16, few values, so equal
+    scores straddle the k-th place; and each is a dyadic fraction, which
+    the CPU's rsqrt returns exactly in every fusion (it rounds other
+    scores by an ulp differently from one compiled program to another)."""
+    bits = np.zeros((levels, 64), np.uint8)
+    for row in bits:
+        row[rng.choice(64, 16, replace=False)] = 1
+    return pack_bits(bits)[rng.integers(0, levels, n)]
+
+
+@pytest.mark.parametrize("k", [1, 10, 100, 128])
+@pytest.mark.parametrize("levels", [2, 7, 60, 3000])
+@pytest.mark.parametrize("carry_inf", [False, True])
+def test_two_stage_merge_matches_one_stage(k, levels, carry_inf):
+    rng = np.random.default_rng(k * 1000 + levels)
+    B, chunk = 6, 8192
+    g = ops.topk_group_width(chunk, k, chunk)
+    assert g > 0
+    sims = rng.integers(0, levels, (B, chunk)).astype(np.float32) / levels
+    sims[rng.random((B, chunk)) < 0.2] = -np.inf      # masked rows
+    sims[0] = -np.inf
+    if carry_inf:
+        best = np.full((B, k), -np.inf, np.float32)
+        best_ids = np.full((B, k), -1, np.int32)
+    else:
+        best = -np.sort(-rng.integers(0, levels, (B, k)) / levels, axis=1)
+        best = best.astype(np.float32)
+        best_ids = np.sort(rng.choice(4 * chunk, (B, k)), axis=1)
+        best_ids = best_ids.astype(np.int32)
+    args = (jnp.asarray(best), jnp.asarray(best_ids), jnp.asarray(sims),
+            jnp.int32(5 * chunk))
+    got = ops._merge_topk_chunk(*args, k, g)
+    want = _one_stage_merge(*args, k)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+# (k, chunk, N): the first four merge in two stages, the last two in one
+_MERGE_CASES = [
+    (1, 64, 1000), (10, 512, 3000), (100, 2048, 5000), (128, 4096, 9000),
+    (100, 64, 1000), (10, 16, 300),
+]
+
+
+@pytest.mark.parametrize("k,chunk,N", _MERGE_CASES)
+@pytest.mark.parametrize("levels", [2, 5, 40])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_scan_topk_two_stage_matches_one_stage(
+    rng, k, chunk, N, levels, masked, use_pallas
+):
+    two_stage = (k, chunk, N) in _MERGE_CASES[:4]
+    assert (ops.topk_group_width(N, k, chunk) > 0) == two_stage
+    assert N % chunk != 0
+    B = 5
+    db = jnp.asarray(_weight16_codes(rng, N, levels))
+    q = jnp.asarray(np.concatenate(
+        [_weight16_codes(rng, B - 1, levels), _weight16_codes(rng, 1, 1)]
+    ))
+    n_valid = jnp.int32(N - chunk // 2 - 3) if masked else None
+    got = ops.scan_topk(q, db, k, chunk=chunk, use_pallas=use_pallas,
+                        n_valid=n_valid)
+    want = _one_stage_scan_topk(q, db, k, chunk, use_pallas, n_valid)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
 # ------------------------------------------------- block-max pruned scan
 @pytest.mark.parametrize("use_pallas", [False, True])
 @pytest.mark.parametrize("mode", ["clustered", "uniform"])

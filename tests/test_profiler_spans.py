@@ -1,5 +1,6 @@
 """The program's spans on the JAX profiler's clock, and the counters
-beside them (``d2h.bytes``, ``engine.batches``, ``launches.scan_topk``).
+beside them (``d2h.bytes``, ``engine.batches``, ``launches.scan_topk``
+and ``launches.scan_topk.two_stage``).
 
 A profiler trace is recorded on the CPU around annotated ``knn_batch``
 calls and read with the chip benchmark's own reduction
@@ -118,6 +119,38 @@ def test_scan_d2h_bytes_and_launches_per_batch():
     assert delta["engine.batches"] == 3
     assert delta["launches.scan_topk"] == 3
     assert delta["d2h.bytes"] == 3 * b_pad * k_fetch * 4
+
+
+def test_two_stage_merge_counted_per_launch_and_on_the_dispatch_span():
+    n, k = 4096, 7
+    db, q = _codes(n, 5, seed=4)
+    names = ("launches.scan_topk", "launches.scan_topk.two_stage")
+
+    def launches(eng, batches):
+        before = {c: REGISTRY.value(c) for c in names}
+        prev = obs_trace.set_tracer(Tracer(enabled=True))
+        try:
+            for _ in range(batches):
+                eng.knn_batch(q, k)
+            spans = obs_trace.current().snapshot()
+        finally:
+            obs_trace.set_tracer(prev)
+        return [REGISTRY.value(c) - before[c] for c in names], spans
+
+    # the device scan at its default block: the two-stage merge
+    eng = make_engine("linear_scan", db, 64, compute_backend="pallas")
+    k_fetch = min(n, ops.pad_bucket(k + eng._topk_slack, minimum=8))
+    group = ops.topk_group_width(n, k_fetch)
+    assert group > 0
+    counts, spans = launches(eng, 3)
+    assert counts == [3, 3]
+    dispatch = [s["args"] for s in spans if s["name"] == "scan.dispatch"]
+    assert [(a["k"], a["group"]) for a in dispatch] == [(k_fetch, group)] * 3
+
+    # blocks of 64 codes leave too few groups: the direct merge
+    eng = make_engine("sharded_scan", db, 64, num_shards=2, chunk=64)
+    counts, _ = launches(eng, 2)
+    assert counts == [4, 0]
 
 
 def test_every_engine_counts_its_batches():
