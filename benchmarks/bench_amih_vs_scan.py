@@ -97,13 +97,14 @@ def _verify_launches(engine) -> int:
 
 
 def _probe_launch_counts():
-    """(walk, scan) device probe launch counters so far, read from the
-    metrics registry (repro.obs.metrics — the counters exist as 0 even
-    before jax/the device probe path was ever imported)."""
+    """(walk, scan fallback) device probe launch counters so far, read
+    from the metrics registry (repro.obs.metrics — the counters exist as
+    0 even before jax/the device probe path was ever imported). The AMIH
+    engines run ``scan_topk`` only as the walk's fallback."""
     from repro.obs.metrics import REGISTRY
 
     return (REGISTRY.value("launches.device_probe"),
-            REGISTRY.value("launches.device_probe_scan"))
+            REGISTRY.value("launches.scan_topk"))
 
 
 def _time_batched(engine, qs, k, batch):

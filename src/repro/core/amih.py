@@ -190,13 +190,9 @@ class AMIHIndex:
     probe_backend: str = "host"
     # Device-path schedule bound: max precomputed probe-stream entries
     # per (p, z). Walks that would exceed it are truncated and finish
-    # through the fused scan fallback (the device analogue of the host
+    # through the scan fallback (the device analogue of the host
     # enumeration-cap guard).
     probe_stream_cap: int = 1 << 16
-    # Device-path launch shape: True (default) fuses every z-group of a
-    # batch into ONE walk launch via the schedule stack; False keeps the
-    # PR 6 one-launch-per-z-group shape (the fused path's parity oracle).
-    probe_fused: bool = True
     # Grouped verification dispatches so far (one per (z-group, tuple-step)
     # with fresh candidates, unless a step exceeds verify_elem_budget and
     # is chunked). Benchmarks/tests assert launch economy through this.
@@ -230,7 +226,6 @@ class AMIHIndex:
         device: Optional[object] = None,
         probe_backend: str = "host",
         probe_stream_cap: int = 1 << 16,
-        probe_fused: bool = True,
     ) -> "AMIHIndex":
         if verify_backend not in ("numpy", "pallas"):
             raise ValueError(f"unknown verify_backend {verify_backend!r}")
@@ -261,7 +256,7 @@ class AMIHIndex:
             p=p, m=m, db_words=db_words, tables=tables,
             verify_backend=verify_backend, id_offset=id_offset,
             device=device, probe_backend=probe_backend,
-            probe_stream_cap=probe_stream_cap, probe_fused=probe_fused,
+            probe_stream_cap=probe_stream_cap,
         )
         if verify_backend == "pallas":
             index.db_dev  # upload once, at build time
@@ -450,12 +445,11 @@ class AMIHIndex:
         With ``probe_backend="device"`` the whole group loop is replaced
         by the fused device walk (ONE launch for the whole batch — every
         z-group shares it via the schedule stack — plus at most one
-        scan-fallback launch; ``probe_fused=False`` restores the PR 6
-        one-launch-per-z-group shape): results and the early-termination
+        scan-fallback launch): results and the early-termination
         contract are identical, but ``enumeration_cap`` and ``overlap``
         are no-ops there — the device path bounds work through
-        ``probe_stream_cap`` / the fused scan, and has no host loop left
-        to overlap."""
+        ``probe_stream_cap`` / the scan fallback, and has no host loop
+        left to overlap."""
         if self.probe_backend == "device":
             from .probe_device import run_groups_device
 

@@ -57,9 +57,11 @@ __all__ = [
     "EngineStats",
     "SearchEngine",
     "available_backends",
+    "device_scan_knn",
     "make_engine",
     "probe_cache_snapshot",
     "register_engine",
+    "topk_slack",
 ]
 
 
@@ -240,9 +242,8 @@ def make_engine(
                           ``m``, ``verify_backend`` ("numpy" | "pallas"),
                           ``probe_backend`` ("host" | "device" — the
                           fused probing walk: ONE launch for the whole
-                          batch, every z-group stacked into it;
-                          ``probe_fused=False`` restores one launch per
-                          z-group), ``probe_stream_cap``,
+                          batch, every z-group stacked into it),
+                          ``probe_stream_cap``,
                           ``enumeration_cap``, ``query_cache_size``,
                           ``overlap_verify``, ``device`` (where the
                           index and its launches live; JAX's default
@@ -257,7 +258,7 @@ def make_engine(
                           dispatched to all devices without blocking.
                           sharding knobs as above plus ``m``,
                           ``verify_backend``, ``probe_backend``,
-                          ``probe_fused``, ``enumeration_cap``,
+                          ``enumeration_cap``,
                           ``probe_workers``, ``probe_mode``,
                           ``prime_bound``.
       - "cluster"       — cross-host coordinator over worker processes
@@ -336,17 +337,10 @@ class LinearScanEngine(SearchEngine):
 
     name = "linear_scan"
 
-    # Device preselect slack: candidates fetched beyond k so float32
-    # rounding at the selection boundary cannot evict a true top-k item.
-    # Distinct Eq. 3 sims differ by >~1/p^3 (integer cross-multiplication
-    # bound), which stays well above float32 resolution for p <= ~192;
-    # beyond that, sims can collapse in float32, so the slack grows with p
-    # to keep room for a whole collapsed boundary population. Candidates
-    # with *identical* float64 sims are genuine ties (any k of them is a
-    # correct answer), so only distinct-sim collisions matter.
+    # Device preselect slack: candidates fetched beyond k (``topk_slack``).
     @property
     def _topk_slack(self) -> int:
-        return 16 + max(0, self.p - 128) // 4
+        return topk_slack(self.p)
 
     def __init__(
         self,
@@ -420,52 +414,88 @@ class LinearScanEngine(SearchEngine):
         return ids_out, sims_out, stats
 
     def _knn_batch_device(self, q, k_eff):
-        """Device streaming top-K preselect + exact float64 host rerank.
-
-        Both the fetch size and the batch dim are padded to power-of-two
-        buckets (zero query rows score 0.0 everywhere and are sliced off),
-        so the jitted ``scan_topk`` retraces O(log) times per axis instead
-        of once per distinct (B, k).
-        """
+        """Device streaming top-K preselect + exact float64 host rerank
+        (``device_scan_knn``) over the codes uploaded on first use."""
         import jax.numpy as jnp
 
-        from ..kernels import ops
-
-        tr = _obs.current()
         if self._db_dev is None:
-            with tr.span("scan.upload_codes", cat="scan", n=self.n):
+            with _obs.current().span("scan.upload_codes", cat="scan",
+                                     n=self.n):
                 self._db_dev = jnp.asarray(self.db_words)
-        B = q.shape[0]
-        k_fetch = min(
-            self.n, ops.pad_bucket(k_eff + self._topk_slack, minimum=8)
+        return device_scan_knn(q, self.db_words, self._db_dev, k_eff, self.p)
+
+
+def topk_slack(p: int) -> int:
+    """Candidates the device scan fetches beyond k, so that float32
+    rounding at the selection boundary cannot evict a true top-k code.
+    Distinct Eq. 3 sims differ by >~1/p^3 (integer cross-multiplication
+    bound), which stays well above float32 resolution for p <= ~192;
+    beyond that, sims can collapse in float32, so the slack grows with p
+    to keep room for a whole collapsed boundary population. Candidates
+    with *identical* float64 sims are genuine ties (any k of them is a
+    correct answer), so only distinct-sim collisions matter."""
+    return 16 + max(0, p - 128) // 4
+
+
+def device_scan_knn(q, db_words, db_dev, k, p, *, n_valid=None,
+                    device=None):
+    """Exact top-``k`` of each query row by the streaming device scan
+    (``kernels/ops.scan_topk`` over the device-resident ``db_dev``) and a
+    float64 host rerank of its ``k + topk_slack(p)`` candidates against
+    the host copy ``db_words``: (ids int64 (B, k), sims float64 (B, k)),
+    each row ordered by (sim descending, id ascending) — bit-identical to
+    ``linear_scan_knn``. ``k`` is at most the number of codes.
+
+    Both the fetch size and the batch dim are padded to power-of-two
+    buckets (zero query rows score 0.0 everywhere and are sliced off),
+    so the jitted ``scan_topk`` retraces O(log) times per axis instead
+    of once per distinct (B, k). ``n_valid`` masks rows of a padded
+    ``db_dev`` past the real codes; ``device`` places the queries next to
+    a committed ``db_dev`` and counts the launch under
+    ``launches.device.<device>`` too. The scan engine and the AMIH
+    device walk's fallback both answer through here.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import ops
+
+    tr = _obs.current()
+    B = q.shape[0]
+    n = db_words.shape[0]
+    k_fetch = min(n, ops.pad_bucket(k + topk_slack(p), minimum=8))
+    with tr.span("scan.prep", cat="scan", B=B):
+        Bp = ops.pad_bucket(B, minimum=8)
+        qp = np.zeros((Bp, q.shape[1]), dtype=q.dtype)
+        qp[:B] = q
+        qp = jax.device_put(qp, device) if device is not None else (
+            jnp.asarray(qp)
         )
-        with tr.span("scan.prep", cat="scan", B=B):
-            Bp = ops.pad_bucket(B, minimum=8)
-            qp = np.zeros((Bp, q.shape[1]), dtype=q.dtype)
-            qp[:B] = q
-            qp = jnp.asarray(qp)
-        group = ops.topk_group_width(self.n, k_fetch)
-        with tr.span("scan.dispatch", cat="scan", B=B, k=k_fetch,
-                     group=group):
-            _REG.counter("launches.scan_topk").add(1)
-            if group:
-                _REG.counter("launches.scan_topk.two_stage").add(1)
-            _, ids32 = ops.scan_topk(
-                qp, self._db_dev, k_fetch, use_pallas=ops.on_tpu()
-            )
-        with tr.span("scan.fetch", cat="scan", B=B, k=k_fetch):
-            ops.count_d2h(ids32)
-            fetched = np.asarray(ids32)[:B].astype(np.int64)  # (B, k_fetch)
-        ids_out = np.empty((B, k_eff), dtype=np.int64)
-        sims_out = np.empty((B, k_eff), dtype=np.float64)
-        with tr.span("scan.rescore", cat="scan", B=B, k=k_eff):
-            for i in range(B):
-                cand = fetched[i]
-                sub = sims_for_ids(q[i], self.db_words, cand)  # exact f64
-                order = np.lexsort((cand, -sub))[:k_eff]
-                ids_out[i] = cand[order]
-                sims_out[i] = sub[order]
-        return ids_out, sims_out
+    group = ops.topk_group_width(db_dev.shape[0], k_fetch)
+    with tr.span("scan.dispatch", cat="scan", B=B, k=k_fetch,
+                 group=group):
+        _REG.counter("launches.scan_topk").add(1)
+        if group:
+            _REG.counter("launches.scan_topk.two_stage").add(1)
+        if device is not None:
+            _REG.counter("launches.device." + ops.device_key(device)).add(1)
+        masked = {} if n_valid is None else {"n_valid": n_valid}
+        _, ids32 = ops.scan_topk(
+            qp, db_dev, k_fetch, use_pallas=ops.on_tpu(), **masked
+        )
+    with tr.span("scan.fetch", cat="scan", B=B, k=k_fetch):
+        ops.count_d2h(ids32)
+        fetched = np.asarray(ids32)[:B].astype(np.int64)  # (B, k_fetch)
+    ids_out = np.empty((B, k), dtype=np.int64)
+    sims_out = np.empty((B, k), dtype=np.float64)
+    with tr.span("scan.rescore", cat="scan", B=B, k=k):
+        for i in range(B):
+            cand = fetched[i]
+            sub = sims_for_ids(q[i], db_words, cand)  # exact f64
+            order = np.lexsort((cand, -sub))[:k]
+            ids_out[i] = cand[order]
+            sims_out[i] = sub[order]
+    return ids_out, sims_out
 
 
 @register_engine
@@ -616,7 +646,6 @@ class AMIHEngine(SearchEngine):
         overlap_verify: bool = False,
         probe_backend: str = "host",
         probe_stream_cap: int = 1 << 16,
-        probe_fused: bool = True,
         device: Optional[Any] = None,
         **cfg: Any,
     ) -> "AMIHEngine":
@@ -629,7 +658,6 @@ class AMIHEngine(SearchEngine):
             db_words, p, m=m, verify_backend=verify_backend,
             device=device, probe_backend=probe_backend,
             probe_stream_cap=probe_stream_cap,
-            probe_fused=probe_fused,
         )
         return cls(index, enumeration_cap, query_cache_size, overlap_verify)
 

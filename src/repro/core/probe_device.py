@@ -3,7 +3,7 @@
 The host probing loop in ``amih.py`` walks the (p, z) tuple sequence one
 step at a time: enumerate substring probes, look buckets up in host CSR
 tables, verify fresh candidates, emit. Every step is a host round-trip.
-This module compiles the whole walk into ONE jitted launch per z-group:
+This module compiles the whole walk into ONE jitted launch per batch:
 
 1.  **Schedule** (``DeviceSchedule``): the probing sequence depends only
     on (p, z) — not the query — so the entire walk is precomputed as flat
@@ -20,34 +20,37 @@ This module compiles the whole walk into ONE jitted launch per z-group:
 2.  **CSR** (``build_device_csr``): each ``_SubTable``'s buckets become a
     dense offsets table (``offsets[s, v] .. offsets[s, v + 1]`` bounds
     bucket ``v`` of table ``s``) plus one shared sorted-ids matrix,
-    committed next to ``AMIHIndex.db_dev`` — bucket lookup on device is
-    two gathers.
+    committed next to the padded codes — bucket lookup on device is two
+    gathers. Dense offsets cost 4 (2^w + 1) bytes a table, so their
+    total is bounded (``MAX_OFFSET_BYTES``).
 
 3.  **Walk kernel** (``kernels/device_probe.py``): a ``lax.while_loop``
     consumes the stream in tiles, expands bucket ranges into candidate
     slots (at most ``cap`` per query per iteration — oversized buckets
     are resumed across iterations), gathers + popcount-verifies the
     candidates (Pallas kernel on TPU, XLA reference elsewhere), and
-    scatter-mins each candidate's exact walk position into a per-query
-    position map. Dedup is free: rediscovering a candidate scatters the
-    same position. Early termination is the paper's Prop. 2 bound in
-    walk-position space: a query is done when at least k codes have
-    position <= the last *completed* step (pigeonhole: those are final)
-    or the walk has passed its ``stop_below`` position.
+    merges each code's exact walk position into a per-query carry of the
+    kf smallest (position, id) pairs met so far, each code once (from
+    the stream block that met it first: ``DeviceSchedule.block_start``).
+    Early termination is the paper's Prop. 2 bound in walk-position
+    space: a query is done when at least k codes have position <= the
+    last *completed* step (pigeonhole: those are final) or the walk has
+    passed its ``stop_below`` position.
 
-4.  **Extraction** (host): the final top-K of query ``qi`` is the k
-    smallest (position, id) pairs of its position map; sims are read from
-    the host float64 ``sims64`` table at those positions, so emitted sims
-    never round-trip through float32 and results are bit-identical to the
-    host path and ``linear_scan_knn`` (including in-tuple ties: ascending
-    id within a position, walk order across positions).
+4.  **Extraction** (host): the final top-K of query ``qi`` is the first
+    k of its carry; sims are read from the host float64 ``sims64`` table
+    at those positions, so emitted sims never round-trip through float32
+    and results are bit-identical to the host path and
+    ``linear_scan_knn`` (including in-tuple ties: ascending id within a
+    position, walk order across positions).
 
 If the schedule could not be fully built (a probe needs more than
 ``KMAX`` flips, or the stream would exceed ``stream_cap`` entries — the
-device analogue of the host enumeration-cap guard), queries still not
-done when the walk exhausts the stream fall back to ONE full-scan verify
-launch (every code's exact position), keeping the launch count O(1) per
-z-group in every case.
+device analogue of the host enumeration-cap guard), or the walk ran out
+of its iteration budget, the queries still not done are answered by the
+exhaustive scan (``engine.device_scan_knn``, the scan engine's own
+program and float64 rescore), each code placed at its walk position
+through its Hamming tuple: ONE more launch for the whole batch.
 """
 
 from __future__ import annotations
@@ -61,8 +64,9 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..obs import trace as _obs
+from ..obs.metrics import REGISTRY as _REG
 from .enumeration import combination_indices
-from .packing import extract_substring, popcount, substring_spans
+from .packing import extract_substring, hamming_tuples, popcount
 from .probing import probing_prefix
 from .tuples import rhat, sim_value
 
@@ -71,7 +75,7 @@ __all__ = [
     "DEFAULT_STREAM_CAP",
     "DeviceSchedule",
     "KMAX",
-    "MAX_OFFSET_WIDTH",
+    "MAX_OFFSET_BYTES",
     "POS_INF",
     "ScheduleStack",
     "build_device_csr",
@@ -91,12 +95,15 @@ __all__ = [
 # walks never get near this.
 KMAX = 8
 
-# "Never probed" sentinel in the per-query position map (int32 max).
+# "Never met" sentinel of the walk's position carry (int32 max).
 POS_INF = np.int32(0x7FFFFFFF)
 
-# Dense CSR offsets spend 4 * (2^w + 1) bytes per table; w <= 20 caps
-# that at ~4 MiB/table. Wider substrings should raise m instead.
-MAX_OFFSET_WIDTH = 20
+# Bound on the dense CSR offsets, 4 * (2^wmax + 1) bytes per table:
+# 128 MiB, the size of a 2^24-code share of 64-bit codes, so the offsets
+# never outgrow the codes they index. It admits the paper's m = 3 at
+# p = 64 (22-bit substrings, 48 MiB); 32-bit substrings (m = 2) would
+# need 32 GiB of dense offsets, i.e. sparse ones.
+MAX_OFFSET_BYTES = 1 << 27
 
 # Stream entries consumed per while_loop iteration (also the schedule's
 # pad margin, so a tile slice never needs clamping).
@@ -110,22 +117,17 @@ DEFAULT_PROBE_CAP = 2048
 # field ``probe_stream_cap`` overrides it per index.
 DEFAULT_STREAM_CAP = 1 << 16
 
-# Done-check cadence inside the while_loop. A check scans the (B, n_pad)
-# position map, but most walks finish within their first tile — checking
-# every iteration lets them exit immediately, which beats amortizing the
-# scan over iterations the query never needed.
-DEFAULT_CHECK_EVERY = 1
-
-_EMPTY_I64 = np.empty(0, dtype=np.int64)
+# ``block_start`` of a (table, a, b) block the stream does not hold.
+NO_BLOCK = np.int32(1 << 30)
 
 
 @dataclass
 class DeviceSchedule:
     """Precomputed device walk for one (p, m, widths, z, stream_cap).
 
-    Host-side metadata (numpy) plus per-device committed jnp bundles
-    (``device_arrays``). Instances are shared process-wide through
-    ``get_schedule`` — treat every array as read-only.
+    Host-side numpy arrays, stacked onto the device by ``ScheduleStack``.
+    Instances are shared process-wide through ``get_schedule`` — treat
+    every array as read-only.
     """
 
     p: int
@@ -151,36 +153,10 @@ class DeviceSchedule:
     maxi1: np.ndarray = field(default=None, repr=False)    # (P,) int32
     maxi0: np.ndarray = field(default=None, repr=False)    # (P,) int32
     cum_subtuples: np.ndarray = field(default=None, repr=False)
-    _dev: Dict[str, dict] = field(default_factory=dict, repr=False)
-
-    def device_arrays(self, device) -> dict:
-        """The committed jnp bundle of the walk arrays for ``device``
-        (built on first use per device, cached on the schedule)."""
-        from ..kernels import ops
-
-        key = ops.device_key(device)
-        bundle = self._dev.get(key)
-        if bundle is None:
-            import jax
-            import jax.numpy as jnp
-
-            put = (
-                (lambda a: jax.device_put(a, device))
-                if device is not None
-                else jnp.asarray
-            )
-            bundle = {
-                "tbl": put(self.tbl),
-                "step_ext": put(self.step_ext),
-                "idx1": put(self.idx1),
-                "idx0": put(self.idx0),
-                "maxi1": put(self.maxi1),
-                "maxi0": put(self.maxi0),
-                "inv_pos": put(self.inv_pos),
-                "widths": put(np.asarray(self.widths, dtype=np.int32)),
-            }
-            self._dev[key] = bundle
-        return bundle
+    # stream entry where the block of (table s, a one-flips, b zero-flips)
+    # starts, NO_BLOCK where the stream holds no such block:
+    # (m, wmax+1, wmax+1) int32
+    block_start: np.ndarray = field(default=None, repr=False)
 
 
 def _build_schedule(
@@ -217,6 +193,7 @@ def _build_schedule(
     maxi1_l: List[np.ndarray] = []
     maxi0_l: List[np.ndarray] = []
     probe_counts: List[int] = []
+    block_start = np.full((m, wmax + 1, wmax + 1), NO_BLOCK, dtype=np.int32)
     total = 0
     built = 0
     complete = False
@@ -245,6 +222,7 @@ def _build_schedule(
                 break
         if abort or total + cnt > stream_cap:
             break
+        entry = total
         for (s, a, b) in new_probes:
             cov = cover[s]
             cov[a] = max(cov.get(a, -1), b)
@@ -267,6 +245,8 @@ def _build_schedule(
                 if b else np.full(C0, -1, dtype=np.int32)
             )
             e = C1 * C0
+            block_start[s, a, b] = entry
+            entry += e
             tbl_l.append(np.full(e, s, dtype=np.int32))
             step_l.append(np.full(e, t, dtype=np.int32))
             idx1_l.append(np.repeat(i1, C0, axis=0))
@@ -304,6 +284,7 @@ def _build_schedule(
     sched.cum_subtuples = np.concatenate(
         ([0], np.cumsum(probe_counts, dtype=np.int64))
     )
+    sched.block_start = block_start
     return sched
 
 
@@ -478,8 +459,13 @@ class ScheduleStack:
             g_end[:G] = self.g_end
             pp2 = (self.p + 1) * (self.p + 1)
             inv = np.full((G_pad, pp2), POS_INF, dtype=np.int32)
+            blocks = np.full(
+                (G_pad, self.m * (self.wmax + 1) ** 2), NO_BLOCK,
+                dtype=np.int32,
+            )
             for i, s in enumerate(self.scheds):
                 inv[i] = s.inv_pos
+                blocks[i] = s.block_start.reshape(-1)
 
             import jax
             import jax.numpy as jnp
@@ -499,6 +485,7 @@ class ScheduleStack:
                 "maxi1": put(self.maxi1),
                 "maxi0": put(self.maxi0),
                 "inv_pos": put(inv),
+                "block_start": put(blocks),
                 "widths": put(np.asarray(self.widths, dtype=np.int32)),
             }
             self._dev[key] = (self.version, bundle)
@@ -531,39 +518,48 @@ def get_schedule_stack(
 # ------------------------------------------------------------------- CSR
 def build_device_csr(index) -> dict:
     """Device-resident CSR of every ``_SubTable``, committed to
-    ``index.device`` next to ``db_dev``.
+    ``index.device``.
 
     ``offsets`` is dense over bucket values — (m, 2^wmax + 1) int32, so a
-    bucket lookup is two gathers with no per-table searchsorted on device;
-    ``ids`` is the per-table sorted id rows padded to ``n_pad`` with the
-    out-of-bounds marker ``n_pad`` (dropped by the position scatter);
-    ``db_pad`` zero-pads the packed codes to ``n_pad`` rows for static
-    gather shapes.
+    bucket lookup is two gathers with no per-table searchsorted on device
+    — and its bytes are bounded by ``MAX_OFFSET_BYTES``; ``ids`` is the
+    per-table sorted id rows padded to ``n_pad`` with the out-of-bounds
+    marker ``n_pad``; ``db_pad`` zero-pads the packed codes to ``n_pad``
+    rows for static gather shapes; ``sub_masks`` (m, W) uint32 holds the
+    bits of each table's substring in the code words.
     """
     from ..kernels import ops
 
     widths = [t.width for t in index.tables]
     wmax = max(widths)
-    if wmax > MAX_OFFSET_WIDTH:
+    m = index.m
+    offset_bytes = 4 * m * ((1 << wmax) + 1)
+    if offset_bytes > MAX_OFFSET_BYTES:
         raise ValueError(
-            f"probe_backend='device' needs substring width <= "
-            f"{MAX_OFFSET_WIDTH} bits for the dense CSR offsets "
-            f"(got {wmax}); build with larger m (>= "
-            f"{-(-index.p // MAX_OFFSET_WIDTH)} for p={index.p})"
+            f"probe_backend='device' keeps dense CSR offsets, "
+            f"4 * (2^w + 1) bytes per table: {m} tables of {wmax}-bit "
+            f"substrings need {offset_bytes / 2**20:.0f} MiB, over the "
+            f"{MAX_OFFSET_BYTES >> 20} MiB bound; substrings this wide "
+            f"need sparse bucket offsets, which the device walk does not "
+            f"have"
         )
     n = index.n
     n_pad = ops.pad_bucket(n, minimum=8)
-    m = index.m
+    W = index.db_words.shape[1]
     offsets = np.full((m, (1 << wmax) + 1), n, dtype=np.int32)
     ids = np.full((m, n_pad), n_pad, dtype=np.int32)
+    bit = np.arange(W * 32)
+    masks = np.zeros((m, W), dtype=np.uint32)
     for s, table in enumerate(index.tables):
         w = table.width
         offsets[s, : (1 << w) + 1] = np.searchsorted(
             table.sorted_vals, np.arange((1 << w) + 1), side="left"
         ).astype(np.int32)
         ids[s, :n] = table.sorted_ids
-    db_pad = np.zeros((n_pad, index.db_words.shape[1]),
-                      dtype=index.db_words.dtype)
+        inside = ((bit >= table.lo) & (bit < table.hi)).reshape(W, 32)
+        masks[s] = (inside.astype(np.uint64)
+                    << np.arange(32, dtype=np.uint64)).sum(axis=1)
+    db_pad = np.zeros((n_pad, W), dtype=index.db_words.dtype)
     db_pad[:n] = index.db_words
 
     import jax
@@ -578,6 +574,7 @@ def build_device_csr(index) -> dict:
         "offsets": put(offsets),
         "ids": put(ids),
         "db_pad": put(db_pad),
+        "sub_masks": put(masks),
         "n": n,
         "n_pad": n_pad,
         "wmax": wmax,
@@ -612,32 +609,21 @@ def _pow_arrays(
 
 
 # ---------------------------------------------------------------- driver
-def _extract(pm, ts, n, k, sims64):
-    """The k smallest (position, id) pairs of one query's position map:
+def _extract(pos, ids, ts, k, sims64):
+    """The first k of one query's (position, id) pairs, sorted by
+    (position, id), that lie at or before walk position ``ts``:
     (out_ids local int64, out_pos int64, out_sims float64)."""
-    # work on the found subset only: the full-width (n,) compare is one
-    # cheap pass, everything after is O(cnt log cnt)
-    idx = np.flatnonzero(pm <= ts)
-    take = min(k, idx.size)
-    if take > 0:
-        pos_f = pm[idx].astype(np.int64)
-        order = np.argsort(pos_f * n + idx)[:take]
-        out_ids = idx[order].astype(np.int64)
-        out_pos = pos_f[order]
-        out_sims = sims64[out_pos]
-    else:
-        out_ids = _EMPTY_I64
-        out_pos = _EMPTY_I64
-        out_sims = np.empty(0, dtype=np.float64)
-    return out_ids, out_pos, out_sims
+    take = min(k, int(np.searchsorted(pos, ts, side="right")))
+    out_pos = pos[:take].astype(np.int64)
+    return ids[:take].astype(np.int64), out_pos, sims64[out_pos]
 
 
-def _record_stats(st, sched, pm, out_pos, take, probes, retrieved,
+def _record_stats(st, sched, verified, out_pos, probes, retrieved,
                   scanned, r_hat):
     st.probes += int(probes)
     st.retrieved += int(retrieved)
-    st.verified += int((pm != POS_INF).sum())
-    t_last = int(out_pos[-1]) if take else -1
+    st.verified += int(verified)
+    t_last = int(out_pos[-1]) if out_pos.size else -1
     st.tuples_processed += t_last + 1
     if t_last >= 0:
         st.max_radius = max(st.max_radius, int(sched.cum_maxrad[t_last]))
@@ -736,47 +722,68 @@ def dispatch_groups_device(
         csr=csr,
         p=index.p,
         device=index.device,
-        blocking=False,
     )
     index.verify_launches += 1
     return _PendingGroups(q_words, k, zs, gid, t_stop, stack, handle)
 
 
+def _scan_fallback(index, pending, rows, res):
+    """Answer the bailed queries ``rows`` by the exhaustive device scan
+    and write their (position, id) pairs into ``res`` in the walk's
+    order: each code's walk position is its Hamming tuple's position in
+    the query's schedule (one inverse-position lookup per result)."""
+    from .engine import device_scan_knn
+
+    csr = index.device_csr
+    n, n_pad = csr["n"], csr["n_pad"]
+    k = pending.k
+    q = pending.q_words[rows]
+    ids, _ = device_scan_knn(
+        q, index.db_words, csr["db_pad"], k, index.p,
+        n_valid=None if n == n_pad else np.int32(n),
+        device=index.device,
+    )
+    for j, qi in enumerate(rows):
+        sched = pending.stack.scheds[pending.gid[qi]]
+        r10, r01 = hamming_tuples(q[j], index.db_words[ids[j]])
+        pos = sched.inv_pos[r10 * (index.p + 1) + r01]
+        order = np.lexsort((ids[j], pos))
+        res["pos"][qi] = POS_INF
+        res["pos"][qi, :k] = pos[order]
+        res["ids"][qi, :k] = ids[j, order]
+
+
 def resolve_groups_device(index, pending: _PendingGroups, stats,
                           on_done=None):
     """Block on a dispatched fused walk, finish any bailed queries with
-    ONE cross-group scan launch, and extract results — the whole batch
-    cost two launches at most. Returns finished ``_QueryState``s with
+    ONE exhaustive scan launch, and extract results — the whole batch
+    costs two launches at most. Returns finished ``_QueryState``s with
     the host loop's result contract (LOCAL ids; float64 sims)."""
     from .amih import _QueryState
-    from ..kernels import ops
 
     q_words = pending.q_words
     k = pending.k
     stack = pending.stack
     B = q_words.shape[0]
-    csr = index.device_csr
-    n = csr["n"]
+    n = index.device_csr["n"]
     res = pending.handle.get()
-    posmap = res["posmap"]
-    scanned = np.zeros(B, dtype=bool)
     undone = np.flatnonzero(~res["done"])
+    _REG.counter("amih.queries").add(B)
+    _REG.counter("amih.bailed_queries").add(int(undone.size))
+    _REG.counter("amih.retrieved").add(int(res["retrieved"].sum()))
+    _REG.counter("amih.verified").add(int(res["verified"].sum()))
+    _REG.counter("amih.walk_iters").add(res["iters"])
+    scanned = np.zeros(B, dtype=bool)
     if undone.size:
         # truncated schedules / budget bails: finish every straggler of
-        # every group with ONE exhaustive verify launch — positions are
+        # every group with ONE exhaustive scan launch — positions are
         # exact, so results are unchanged, and the batch total stays at
         # two launches
         with _obs.current().span("amih.fallback", cat="amih",
                                  B=int(undone.size)):
-            pm2 = ops.device_probe_scan_multi_launch(
-                np.ascontiguousarray(q_words[undone]),
-                pending.gid[undone],
-                stack=stack,
-                csr=csr,
-                p=index.p,
-                device=index.device,
-            )
-        posmap[undone] = pm2
+            res["pos"] = res["pos"].copy()
+            res["ids"] = res["ids"].copy()
+            _scan_fallback(index, pending, undone, res)
         scanned[undone] = True
         index.verify_launches += 1
 
@@ -785,14 +792,14 @@ def resolve_groups_device(index, pending: _PendingGroups, stats,
         for qi in range(B):
             sched = stack.scheds[pending.gid[qi]]
             out_ids, out_pos, out_sims = _extract(
-                posmap[qi, :n], int(pending.t_stop[qi]), n, k, sched.sims64
+                res["pos"][qi], res["ids"][qi], int(pending.t_stop[qi]), k,
+                sched.sims64,
             )
-            take = out_ids.size
             st = None if stats is None else stats[qi]
             if st is not None:
                 _record_stats(
-                    st, sched, posmap[qi, :n], out_pos, take,
-                    res["probes"][qi], res["retrieved"][qi],
+                    st, sched, n if scanned[qi] else res["verified"][qi],
+                    out_pos, res["probes"][qi], res["retrieved"][qi],
                     bool(scanned[qi]), rhat(int(pending.zs[qi])),
                 )
             state = _QueryState(
@@ -807,7 +814,7 @@ def resolve_groups_device(index, pending: _PendingGroups, stats,
                 out_sims=out_sims,
                 stats=st,
                 scanned=bool(scanned[qi]),
-                done=take >= k,
+                done=out_ids.size >= k,
             )
             states.append(state)
             if on_done is not None and state.done:
@@ -830,141 +837,9 @@ def run_groups_device(
     """Device-path replacement for ``AMIHIndex._run_groups``: ONE fused
     walk launch (plus at most one scan-fallback launch) for the whole
     batch, then host extraction. Returns finished ``_QueryState``s with
-    the same result contract as the host loop (LOCAL ids; float64 sims).
-
-    ``index.probe_fused=False`` keeps the PR 6 shape — one walk launch
-    per z-group — as a parity oracle; results are bit-identical."""
-    if not getattr(index, "probe_fused", True):
-        return _run_groups_device_grouped(
-            index, q_words, k, stats, stop_below, on_done
-        )
+    the same result contract as the host loop (LOCAL ids; float64
+    sims)."""
     if q_words.shape[0] == 0:
         return []
-    if np.unique(popcount(q_words)).size == 1:
-        # single z-group (every B=1 call lands here): the stacked
-        # kernel buys nothing over the per-group launch — same ONE walk
-        # launch, but the per-group kernel's smaller operands dispatch
-        # measurably faster at single-query latency. Results identical.
-        return _run_groups_device_grouped(
-            index, q_words, k, stats, stop_below, on_done
-        )
     pending = dispatch_groups_device(index, q_words, k, stop_below)
     return resolve_groups_device(index, pending, stats, on_done=on_done)
-
-
-def _run_groups_device_grouped(
-    index,
-    q_words: np.ndarray,
-    k: int,
-    stats,
-    stop_below: Optional[np.ndarray] = None,
-    on_done=None,
-):
-    """The PR 6 per-z-group device path (one walk launch per z-group):
-    kept as the fused path's parity oracle and the
-    ``probe_fused=False`` escape hatch."""
-    from .amih import _QueryState
-    from ..kernels import ops
-
-    B = q_words.shape[0]
-    zs = popcount(q_words)
-    groups: Dict[int, List[int]] = {}
-    for qi in range(B):
-        groups.setdefault(int(zs[qi]), []).append(qi)
-
-    csr = index.device_csr
-    widths = csr["widths"]
-    wmax = csr["wmax"]
-    n = csr["n"]
-    states: List[_QueryState] = []
-    for z, qis in groups.items():
-        sched = get_schedule(
-            index.p, index.m, widths, z, index.probe_stream_cap
-        )
-        Bg = len(qis)
-        q_grp = np.ascontiguousarray(q_words[qis])
-        q_sub, z_sub = _query_substrings(index, q_grp)
-        pow1, pow0 = _pow_arrays(q_sub, z_sub, widths, wmax)
-        if stop_below is None:
-            t_stop = np.full(Bg, sched.L - 1, dtype=np.int32)
-        else:
-            # snapshot of the live bounds: bounds only ever rise, so a
-            # stale (lower) value is always still a valid lower bound
-            t_stop = (
-                np.searchsorted(
-                    -sched.sims64, -stop_below[qis], side="right"
-                )
-                - 1
-            ).astype(np.int32)
-
-        res = ops.device_probe_walk_launch(
-            q_grp,
-            q_sub.astype(np.int32),
-            z_sub,
-            pow1,
-            pow0,
-            t_stop,
-            k,
-            sched=sched,
-            csr=csr,
-            p=index.p,
-            device=index.device,
-        )
-        index.verify_launches += 1
-        posmap = res["posmap"]
-        done_dev = res["done"]
-        scanned = np.zeros(Bg, dtype=bool)
-        undone = np.flatnonzero(~done_dev)
-        if undone.size:
-            # truncated schedule: finish the stragglers with ONE
-            # exhaustive verify launch (the host enumeration-cap
-            # fallback, fused) — positions are exact, so results are
-            # unchanged, and the z-group total stays at two launches
-            pm2 = ops.device_probe_scan_launch(
-                q_grp[undone],
-                sched=sched,
-                csr=csr,
-                p=index.p,
-                device=index.device,
-            )
-            posmap = posmap.copy()  # the device-backed view is read-only
-            posmap[undone] = pm2
-            scanned[undone] = True
-            index.verify_launches += 1
-
-        r_hat = rhat(z)
-        for gi, qi in enumerate(qis):
-            pm = posmap[gi, :n]
-            out_ids, out_pos, out_sims = _extract(
-                pm, int(t_stop[gi]), n, k, sched.sims64
-            )
-            take = out_ids.size
-            st = None if stats is None else stats[qi]
-            if st is not None:
-                _record_stats(
-                    st, sched, pm, out_pos, take,
-                    res["probes"][gi], res["retrieved"][gi],
-                    bool(scanned[gi]), r_hat,
-                )
-            state = _QueryState(
-                qi=qi,
-                q_words=q_words[qi],
-                q_subs=[],
-                z_subs=[],
-                seen=np.empty(0, dtype=bool),
-                cover=[],
-                pending={},
-                out_ids=out_ids,
-                out_sims=out_sims,
-                stats=st,
-                scanned=bool(scanned[gi]),
-                done=take >= k,
-            )
-            states.append(state)
-            if on_done is not None and state.done:
-                on_done(
-                    qi,
-                    out_ids + index.id_offset,
-                    np.asarray(out_sims, dtype=np.float64),
-                )
-    return states

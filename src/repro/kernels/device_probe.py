@@ -1,26 +1,33 @@
 """Fused device-resident AMIH probing walk (paper §4–§5, one launch).
 
-``device_probe_walk`` compiles the whole probe -> bucket-lookup ->
-verify -> top-K pipeline of one z-group into a single jitted
+``device_probe_walk_batched`` compiles the whole probe -> bucket-lookup
+-> verify -> top-K pipeline of a query batch into a single jitted
 ``lax.while_loop``: each iteration consumes a tile of the precomputed
-probe stream (repro.core.probe_device.DeviceSchedule), expands the CSR
-bucket ranges into at most ``cap`` candidate slots per query, gathers
-the candidate codes from the device-resident padded DB, popcount-
-verifies them (the ``verify_tuples_grouped`` Pallas kernel on TPU, the
-XLA reference elsewhere), and scatter-mins each candidate's exact walk
-position into a per-query (B, n_pad) position map. Rediscoveries
-scatter the same position, so deduplication costs nothing.
+probe stream (repro.core.probe_device.ScheduleStack) per z-group,
+expands the CSR bucket ranges into at most ``cap`` candidate slots per
+query, gathers the candidate codes from the device-resident padded DB,
+popcount-verifies them (the ``verify_tuples_grouped`` Pallas kernel on
+TPU, the XLA reference elsewhere), and merges each new candidate's exact
+walk position into a per-query carry of its ``kf`` smallest (position,
+id) pairs so far.
+
+Each code enters the carry once. A code held in several tables is
+reached once per table, each time from a different block of the stream
+(one block per (table, one-flips a, zero-flips b)); the code's (a, b) in
+every table is a function of the code and the query, so the walk looks
+up where each of its blocks starts and keeps the code only from the
+earliest. The walk consumes each group's stream as a prefix, so that
+block has always been probed when a later one is. The same rule counts
+the distinct codes verified, per query.
 
 Early termination is Prop. 2's k-th-cosine bound translated to walk
 positions: after the entries of walk step t are all consumed, every
-code with position <= t is guaranteed present in the map (pigeonhole
-over the Prop. 4 cover), so a query is done once at least ``k``
-positions <= min(t, t_stop) are mapped, or the walk has passed
-``t_stop`` (the per-query stop-below bound; the full walk length when
-unbounded). The check runs every ``check_every`` iterations (it scans
-the position map), and the loop also yields after ``budget``
-iterations: past that point one exhaustive ``device_probe_scan``
-launch is cheaper than continuing to grind tile-by-tile through a
+code with position <= t has been met (pigeonhole over the Prop. 4
+cover), so a query is done once at least ``k`` positions <= min(t,
+t_stop) are in its carry, or the walk has passed ``t_stop`` (the
+per-query stop-below bound; the full walk length when unbounded). The
+loop also yields after ``budget`` iterations: past that point the
+exhaustive scan is cheaper than grinding tile-by-tile through a
 combinatorially deep walk — the device analogue of the host path's
 enumeration-cap scan fallback.
 
@@ -29,19 +36,10 @@ stream entry exceeds ``cap`` candidates for some query, the iteration
 takes ``cap`` of them and resumes the same entry at offset ``off``
 next round, so device memory stays bounded by (B, cap, W) regardless
 of bucket skew.
-
-``device_probe_scan`` is the fallback for truncated schedules (stream
-cap or KMAX abort — the device analogue of the host enumeration-cap
-guard): one launch verifies EVERY code against the still-undone
-queries in chunks of a ``lax.map``, yielding the complete position
-map. Either way a z-group costs O(1) launches.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -68,242 +66,7 @@ def _verify(q_words, gathered, totals, *, p, cap, use_pallas, interpret):
     return ref.verify_tuples_grouped_ref(q_words, gathered, totals, p)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "p", "tile", "cap", "kmax", "check_every", "use_pallas", "interpret"
-    ),
-)
-def device_probe_walk(
-    q_words,      # (B, W) uint32 packed queries
-    q_sub,        # (B, m) int32 query substring values
-    z_sub,        # (B, m) int32 substring popcounts
-    pow1,         # (B, m, wmax+1) int32 one-position bit values
-    pow0,         # (B, m, wmax+1) int32 zero-position bit values
-    t_stop,       # (B,) int32 last walk position to consider (<0: done)
-    k_arr,        # () int32 results wanted per query
-    s_len,        # () int32 real stream entries
-    budget,       # () int32 max iterations before the scan fallback
-    tbl,          # (P,) int32 stream: table id per entry
-    step_ext,     # (P+1,) int32 stream: walk step per entry (ext: built)
-    idx1,         # (P, kmax) int32 one-side combination indices
-    idx0,         # (P, kmax) int32 zero-side combination indices
-    maxi1,        # (P,) int32 largest one-side index (-1: none)
-    maxi0,        # (P,) int32 largest zero-side index (-1: none)
-    widths,       # (m,) int32 substring widths
-    offsets,      # (m, 2^wmax + 1) int32 dense CSR bucket offsets
-    bucket_ids,   # (m, n_pad) int32 CSR sorted ids (pad: n_pad)
-    db_pad,       # (n_pad, W) uint32 zero-padded packed codes
-    inv_pos,      # ((p+1)^2,) int32 packed key -> walk position
-    *,
-    p: int,
-    tile: int,
-    cap: int,
-    kmax: int,
-    check_every: int,
-    use_pallas: bool,
-    interpret: bool,
-):
-    """One fused launch: walk the probe stream to completion or until
-    every query terminates early. Returns (posmap (B, n_pad) int32,
-    probes (B,) int32, retrieved (B,) int32, done (B,) bool,
-    cursor () int32, iters () int32)."""
-    REGISTRY.counter("traces.device_probe_walk").add(1)
-    B = q_words.shape[0]
-    n_pad = db_pad.shape[0]
-    V = offsets.shape[1]
-    wp1 = pow1.shape[2]
-    col = jnp.arange(tile, dtype=jnp.int32)
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    brow = jnp.arange(B, dtype=jnp.int32)[:, None]
-    pow1f = pow1.reshape(B, -1)
-    pow0f = pow0.reshape(B, -1)
-    offsf = offsets.reshape(-1)
-    idsf = bucket_ids.reshape(-1)
-
-    posmap0 = jnp.full((B, n_pad), POS_INF, dtype=jnp.int32)
-    zeros_b = jnp.zeros((B,), dtype=jnp.int32)
-    carry0 = (
-        jnp.int32(0),              # cursor: next stream entry
-        jnp.int32(0),              # off: resume offset into entry cursor
-        t_stop < 0,                # done
-        posmap0,
-        zeros_b,                   # probes (bucket lookups) per query
-        zeros_b,                   # retrieved candidates per query
-        jnp.int32(0),              # iterations
-    )
-
-    def cond(c):
-        cursor, _, done, _, _, _, it = c
-        return (cursor < s_len) & ~done.all() & (it < budget)
-
-    def body(c):
-        cursor, off, done, posmap, probes, retrieved, it = c
-        # -- tile of stream entries (P >= s_len + tile: never clamps)
-        t_tbl = lax.dynamic_slice(tbl, (cursor,), (tile,))
-        t_idx1 = lax.dynamic_slice(idx1, (cursor, 0), (tile, kmax))
-        t_idx0 = lax.dynamic_slice(idx0, (cursor, 0), (tile, kmax))
-        t_m1 = lax.dynamic_slice(maxi1, (cursor,), (tile,))
-        t_m0 = lax.dynamic_slice(maxi0, (cursor,), (tile,))
-        in_stream = (cursor + col) < s_len
-        # -- per-query validity: the canonical combination only names
-        #    actual one/zero positions of THIS query's substring
-        zq = jnp.take(z_sub, t_tbl, axis=1)              # (B, tile)
-        wd = jnp.take(widths, t_tbl)                     # (tile,)
-        valid = (
-            in_stream[None, :]
-            & (~done)[:, None]
-            & (t_m1[None, :] < zq)
-            & (t_m0[None, :] < (wd[None, :] - zq))
-        )
-        # -- bucket value: XOR the OR-ed flip bits into the substring
-        mask = jnp.zeros((B, tile), dtype=jnp.int32)
-        for j in range(kmax):
-            mask = (
-                mask
-                | jnp.take(pow1f, t_tbl * wp1 + t_idx1[:, j], axis=1)
-                | jnp.take(pow0f, t_tbl * wp1 + t_idx0[:, j], axis=1)
-            )
-        vals = jnp.clip(jnp.take(q_sub, t_tbl, axis=1) ^ mask, 0, V - 2)
-        foff = t_tbl[None, :] * V + vals
-        lo = jnp.take(offsf, foff)
-        hi = jnp.take(offsf, foff + 1)
-        sizes = jnp.where(valid, hi - lo, 0)
-        # -- greedy prefix of entries whose total fits cap (per query);
-        #    entry `cursor` may resume mid-bucket at offset `off`
-        adj = jnp.maximum(
-            sizes - jnp.where(col == 0, off, 0)[None, :], 0
-        )
-        csum = jnp.cumsum(adj, axis=1)
-        fits = csum.max(axis=0) <= cap          # monotone: a prefix
-        n_take = fits.sum().astype(jnp.int32)
-        partial = n_take == 0                   # entry 0 alone overflows
-        take_sizes = jnp.where(col[None, :] < n_take, adj, 0)
-        take_sizes = jnp.where(
-            partial,
-            jnp.where(col[None, :] == 0, jnp.minimum(adj, cap), 0),
-            take_sizes,
-        )
-        starts = jnp.cumsum(take_sizes, axis=1) - take_sizes
-        totals = take_sizes.sum(axis=1)         # (B,) <= cap
-        # -- expand ranges to slots: mark each entry's first slot with
-        #    its tile index + 1, running-max fills the rest
-        marks = jnp.zeros((B, cap), dtype=jnp.int32).at[
-            brow, starts
-        ].max((col[None, :] + 1) * (take_sizes > 0), mode="drop")
-        ent = jnp.maximum(lax.cummax(marks, axis=1) - 1, 0)
-        within = slot[None, :] - jnp.take_along_axis(starts, ent, axis=1)
-        base = (
-            jnp.take_along_axis(lo, ent, axis=1)
-            + jnp.where(ent == 0, off, 0)
-            + within
-        )
-        vslot = slot[None, :] < totals[:, None]
-        tt = t_tbl[ent]                         # (B, cap)
-        cand = jnp.take(idsf, tt * n_pad + jnp.clip(base, 0, n_pad - 1))
-        cand = jnp.where(vslot, cand, n_pad)    # n_pad: dropped below
-        gathered = jnp.take(
-            db_pad, jnp.minimum(cand, n_pad - 1), axis=0
-        )                                        # (B, cap, W)
-        keys = _verify(
-            q_words, gathered, totals,
-            p=p, cap=cap, use_pallas=use_pallas, interpret=interpret,
-        )
-        pos = jnp.where(
-            keys >= 0,
-            jnp.take(inv_pos, jnp.maximum(keys, 0)),
-            POS_INF,
-        )
-        # idempotent dedup: a rediscovered candidate scatters its same
-        # exact position; out-of-range cand (pad slots, CSR pad) drops
-        posmap = posmap.at[brow, cand].min(pos, mode="drop")
-        # -- cost counters (resumed entry 0 counts once, at off == 0)
-        probes = probes + jnp.where(
-            partial,
-            (valid[:, 0] & (off == 0)).astype(jnp.int32),
-            (
-                valid
-                & (col[None, :] < n_take)
-                & ~((col[None, :] == 0) & (off > 0))
-            ).sum(axis=1).astype(jnp.int32),
-        )
-        retrieved = retrieved + totals
-        cursor2 = jnp.where(partial, cursor, cursor + n_take)
-        off2 = jnp.where(partial, off + cap, jnp.int32(0))
-        it2 = it + 1
-
-        def check(d):
-            # last fully completed walk step: every code at a position
-            # <= T_comp is in the map (pigeonhole over Prop. 4's cover)
-            T_comp = jnp.take(step_ext, cursor2) - 1
-            eff = jnp.minimum(T_comp, t_stop)
-            cnt = (posmap <= eff[:, None]).sum(axis=1)
-            return d | (cnt >= k_arr) | (T_comp >= t_stop)
-
-        done2 = lax.cond(
-            ((it2 % check_every) == 0) | (cursor2 >= s_len),
-            check,
-            lambda d: d,
-            done,
-        )
-        return (cursor2, off2, done2, posmap, probes, retrieved, it2)
-
-    cursor, _, done, posmap, probes, retrieved, iters = lax.while_loop(
-        cond, body, carry0
-    )
-    return posmap, probes, retrieved, done, cursor, iters
-
-
-@functools.partial(
-    jax.jit, static_argnames=("p", "chunk", "use_pallas", "interpret")
-)
-def device_probe_scan(
-    q_words,      # (B, W) uint32 packed queries
-    db_pad,       # (n_pad, W) uint32 zero-padded packed codes
-    inv_pos,      # ((p+1)^2,) int32 packed key -> walk position
-    n_valid,      # () int32 real code count (pad rows -> POS_INF)
-    *,
-    p: int,
-    chunk: int,
-    use_pallas: bool,
-    interpret: bool,
-):
-    """Exhaustive position map: verify EVERY code against every query in
-    one launch (``lax.map`` over row chunks keeps peak memory at
-    (B, chunk, W)). Returns (B, n_pad) int32 exact walk positions —
-    the fused form of the host enumeration-cap scan fallback."""
-    REGISTRY.counter("traces.device_probe_scan").add(1)
-    B, W = q_words.shape
-    n_pad = db_pad.shape[0]
-    assert n_pad % chunk == 0, (n_pad, chunk)
-    lens = jnp.full((B,), chunk, dtype=jnp.int32)
-
-    def one(args):
-        ci, db_chunk = args
-        gathered = jnp.broadcast_to(db_chunk[None], (B, chunk, W))
-        keys = _verify(
-            q_words, gathered, lens,
-            p=p, cap=chunk, use_pallas=use_pallas, interpret=interpret,
-        )
-        rowid = ci * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        return jnp.where(
-            (keys >= 0) & (rowid[None, :] < n_valid),
-            jnp.take(inv_pos, jnp.maximum(keys, 0)),
-            POS_INF,
-        )
-
-    parts = lax.map(
-        one,
-        (
-            jnp.arange(n_pad // chunk, dtype=jnp.int32),
-            db_pad.reshape(n_pad // chunk, chunk, W),
-        ),
-    )
-    return jnp.transpose(parts, (1, 0, 2)).reshape(B, n_pad)
-
-
 def device_probe_walk_batched(
-    posmap_in,    # (B, n_pad) int32 scratch (donated; contents ignored)
     q_words,      # (B, W) uint32 packed queries (mixed z-groups)
     q_sub,        # (B, m) int32 query substring values
     z_sub,        # (B, m) int32 substring popcounts
@@ -321,7 +84,9 @@ def device_probe_walk_batched(
     idx0,         # (Pt, kmax) int32 zero-side combination indices
     maxi1,        # (Pt,) int32 largest one-side index (-1: none)
     maxi0,        # (Pt,) int32 largest zero-side index (-1: none)
+    block_start,  # (G, m (wmax+1)^2) int32 first entry of each (s, a, b)
     widths,       # (m,) int32 substring widths
+    sub_masks,    # (m, W) uint32 bits of each substring in the code words
     offsets,      # (m, 2^wmax + 1) int32 dense CSR bucket offsets
     bucket_ids,   # (m, n_pad) int32 CSR sorted ids (pad: n_pad)
     db_pad,       # (n_pad, W) uint32 zero-padded packed codes
@@ -330,34 +95,37 @@ def device_probe_walk_batched(
     p: int,
     tile: int,
     cap: int,
+    kf: int,
     kmax: int,
-    check_every: int,
     use_pallas: bool,
     interpret: bool,
 ):
-    """Cross-z-group fused walk: ONE launch per batch, not per z-group.
+    """Cross-z-group fused walk: ONE launch per batch.
 
     Every query carries a ``gid`` row into the concatenated schedule
     stack (``repro.core.probe_device.ScheduleStack``); the carry holds
     one absolute stream cursor and mid-bucket resume offset PER GROUP
     plus per-query done flags, so each group consumes its own stream at
     its own pace while every group's queries share each iteration's
-    lookup/verify work. Per-group tile consumption is the per-z-group
-    kernel's, computed with a segment scatter-max over that group's
-    queries — cursor trajectories (and hence results and counters) are
-    identical to running ``device_probe_walk`` once per group.
+    lookup/verify work. A group's tile consumption is computed with a
+    segment scatter-max over that group's queries, so its cursor moves
+    exactly as it would in a launch of that group alone.
 
     A group advances only while it has an undone query and stream left
     (``active``); exhausted groups freeze and their unfinished queries
-    fall through to the fused multi-group scan. Returns (posmap
-    (B, n_pad) int32, probes (B,) int32, retrieved (B,) int32, done
-    (B,) bool, cursor (G,) int32, iters () int32)."""
+    fall through to the scan fallback. Returns (pos (B, kf) int32 and
+    ids (B, kf) int32: each query's kf smallest (position, id) pairs in
+    that order, POS_INF past the codes met; probes, retrieved and
+    verified (B,) int32: bucket lookups, candidates pulled and distinct
+    codes verified; done (B,) bool, cursor (G,) int32, iters () int32).
+    """
     REGISTRY.counter("traces.device_probe_walk_batched").add(1)
     B = q_words.shape[0]
     G = g_start.shape[0]
     n_pad = db_pad.shape[0]
     Pt = tbl.shape[0]
     V = offsets.shape[1]
+    m = offsets.shape[0]
     wp1 = pow1.shape[2]
     pp2 = inv_pos.shape[1]
     col = jnp.arange(tile, dtype=jnp.int32)
@@ -368,17 +136,24 @@ def device_probe_walk_batched(
     offsf = offsets.reshape(-1)
     idsf = bucket_ids.reshape(-1)
     inv_posf = inv_pos.reshape(-1)
+    bstartf = block_start.reshape(-1)
     g_end_q = jnp.take(g_end, gid)             # (B,)
+    # per-table masked query words, for the candidates' flips per table
+    q_in = q_words[:, None, None, :] & sub_masks[None, None]  # (B,1,m,W)
+    q_out = ~q_words[:, None, None, :] & sub_masks[None, None]
+    blk_row = (gid[:, None, None] * m
+               + jnp.arange(m, dtype=jnp.int32)[None, None, :])
 
-    posmap0 = jnp.full_like(posmap_in, POS_INF)
     zeros_b = jnp.zeros((B,), dtype=jnp.int32)
     carry0 = (
         g_start,                       # (G,) cursor: next stream entry
         jnp.zeros((G,), jnp.int32),    # (G,) off: mid-bucket resume
         t_stop < 0,                    # (B,) done
-        posmap0,
+        jnp.full((B, kf), POS_INF, jnp.int32),   # best positions
+        jnp.full((B, kf), n_pad, jnp.int32),     # their ids
         zeros_b,                       # probes (bucket lookups) per query
         zeros_b,                       # retrieved candidates per query
+        zeros_b,                       # distinct codes verified per query
         jnp.int32(0),                  # iterations
     )
 
@@ -387,11 +162,12 @@ def device_probe_walk_batched(
         return g_undone & (cursor < g_end)
 
     def cond(c):
-        cursor, _, done, _, _, _, it = c
+        cursor, done, it = c[0], c[2], c[-1]
         return group_active(cursor, done).any() & (it < budget)
 
     def body(c):
-        cursor, off, done, posmap, probes, retrieved, it = c
+        (cursor, off, done, best_pos, best_id,
+         probes, retrieved, verified, it) = c
         active_g = group_active(cursor, done)
         curq = jnp.take(cursor, gid)           # (B,)
         offq = jnp.take(off, gid)              # (B,)
@@ -431,7 +207,7 @@ def device_probe_walk_batched(
         sizes = jnp.where(valid, hi - lo, 0)
         # -- greedy per-group prefix of entries whose total fits cap:
         #    the group's limit is the max over ITS queries (segment
-        #    scatter-max), exactly the per-z-group kernel's csum.max
+        #    scatter-max)
         adj = jnp.maximum(
             sizes - jnp.where(col == 0, offq[:, None], 0), 0
         )
@@ -467,7 +243,7 @@ def device_probe_walk_batched(
         vslot = slot[None, :] < totals[:, None]
         tt = jnp.take_along_axis(t_tbl, ent, axis=1)      # (B, cap)
         cand = jnp.take(idsf, tt * n_pad + jnp.clip(base, 0, n_pad - 1))
-        cand = jnp.where(vslot, cand, n_pad)    # n_pad: dropped below
+        cand = jnp.where(vslot, cand, n_pad)
         gathered = jnp.take(
             db_pad, jnp.minimum(cand, n_pad - 1), axis=0
         )                                        # (B, cap, W)
@@ -475,12 +251,28 @@ def device_probe_walk_batched(
             q_words, gathered, totals,
             p=p, cap=cap, use_pallas=use_pallas, interpret=interpret,
         )
+        # -- keep each code from the earliest block that holds it: its
+        #    one-flips a and zero-flips b in every table name the block
+        #    its bucket there lies in; the table whose block starts
+        #    first is the one the walk met it through first
+        g = gathered[:, :, None, :]
+        fa = ref.popcount32(q_in & ~g).sum(axis=-1)       # (B, cap, m)
+        fb = ref.popcount32(q_out & g).sum(axis=-1)
+        first = jnp.argmin(
+            jnp.take(bstartf, (blk_row * wp1 + fa) * wp1 + fb), axis=2
+        )
+        fresh = vslot & (keys >= 0) & (first == tt)
         pos = jnp.where(
-            keys >= 0,
+            fresh,
             jnp.take(inv_posf, gid[:, None] * pp2 + jnp.maximum(keys, 0)),
             POS_INF,
         )
-        posmap = posmap.at[brow, cand].min(pos, mode="drop")
+        best_pos, best_id = lax.sort(
+            (jnp.concatenate([best_pos, pos], axis=1),
+             jnp.concatenate([best_id, cand], axis=1)),
+            num_keys=2,
+        )
+        best_pos, best_id = best_pos[:, :kf], best_id[:, :kf]
         # -- cost counters (resumed entry 0 counts once, at off == 0)
         probes = probes + jnp.where(
             partial_q,
@@ -492,6 +284,7 @@ def device_probe_walk_batched(
             ).sum(axis=1).astype(jnp.int32),
         )
         retrieved = retrieved + totals
+        verified = verified + fresh.sum(axis=1).astype(jnp.int32)
         # frozen groups (all queries done, or stream exhausted) keep
         # their cursor/off: they did no work this iteration
         adv = active_g & ~partial_g
@@ -501,77 +294,18 @@ def device_probe_walk_batched(
             jnp.where(partial_g, off + cap, jnp.int32(0)),
             off,
         )
-        it2 = it + 1
+        # last fully completed walk step OF THE QUERY'S GROUP: every
+        # code at a position <= T_comp has been met (pigeonhole)
+        cq = jnp.minimum(jnp.take(cursor2, gid), Pt - 1)
+        T_comp = jnp.take(step_flat, cq) - 1
+        eff = jnp.minimum(T_comp, t_stop)
+        cnt = (best_pos <= eff[:, None]).sum(axis=1)
+        done2 = done | (cnt >= k_arr) | (T_comp >= t_stop)
+        return (cursor2, off2, done2, best_pos, best_id,
+                probes, retrieved, verified, it + 1)
 
-        def check(d):
-            # last fully completed walk step OF THE QUERY'S GROUP: every
-            # code at a position <= T_comp is in the map (pigeonhole)
-            cq = jnp.minimum(jnp.take(cursor2, gid), Pt - 1)
-            T_comp = jnp.take(step_flat, cq) - 1
-            eff = jnp.minimum(T_comp, t_stop)
-            cnt = (posmap <= eff[:, None]).sum(axis=1)
-            return d | (cnt >= k_arr) | (T_comp >= t_stop)
-
-        done2 = lax.cond(
-            ((it2 % check_every) == 0)
-            | ~group_active(cursor2, done).any(),
-            check,
-            lambda d: d,
-            done,
-        )
-        return (cursor2, off2, done2, posmap, probes, retrieved, it2)
-
-    cursor, _, done, posmap, probes, retrieved, iters = lax.while_loop(
+    (cursor, _, done, best_pos, best_id,
+     probes, retrieved, verified, iters) = lax.while_loop(
         cond, body, carry0
     )
-    return posmap, probes, retrieved, done, cursor, iters
-
-
-def device_probe_scan_multi(
-    q_words,      # (B, W) uint32 packed queries (mixed z-groups)
-    gid,          # (B,) int32 schedule-stack row per query
-    db_pad,       # (n_pad, W) uint32 zero-padded packed codes
-    inv_pos,      # (G, (p+1)^2) int32 packed key -> walk position per row
-    n_valid,      # () int32 real code count (pad rows -> POS_INF)
-    *,
-    p: int,
-    chunk: int,
-    use_pallas: bool,
-    interpret: bool,
-):
-    """Cross-z-group exhaustive position map: ``device_probe_scan`` with
-    a per-query ``gid`` row into the stacked inverse-position tables, so
-    ONE launch finishes the bailed queries of EVERY group in the batch.
-    Returns (B, n_pad) int32 exact walk positions."""
-    REGISTRY.counter("traces.device_probe_scan_multi").add(1)
-    B, W = q_words.shape
-    n_pad = db_pad.shape[0]
-    pp2 = inv_pos.shape[1]
-    inv_posf = inv_pos.reshape(-1)
-    assert n_pad % chunk == 0, (n_pad, chunk)
-    lens = jnp.full((B,), chunk, dtype=jnp.int32)
-
-    def one(args):
-        ci, db_chunk = args
-        gathered = jnp.broadcast_to(db_chunk[None], (B, chunk, W))
-        keys = _verify(
-            q_words, gathered, lens,
-            p=p, cap=chunk, use_pallas=use_pallas, interpret=interpret,
-        )
-        rowid = ci * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        return jnp.where(
-            (keys >= 0) & (rowid[None, :] < n_valid),
-            jnp.take(
-                inv_posf, gid[:, None] * pp2 + jnp.maximum(keys, 0)
-            ),
-            POS_INF,
-        )
-
-    parts = lax.map(
-        one,
-        (
-            jnp.arange(n_pad // chunk, dtype=jnp.int32),
-            db_pad.reshape(n_pad // chunk, chunk, W),
-        ),
-    )
-    return jnp.transpose(parts, (1, 0, 2)).reshape(B, n_pad)
+    return best_pos, best_id, probes, retrieved, verified, done, cursor, iters

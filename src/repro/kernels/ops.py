@@ -29,12 +29,10 @@ __all__ = [
     "SCAN_CHUNK",
     "PendingKeys",
     "PendingWalk",
+    "carry_width",
     "count_d2h",
     "device_key",
-    "device_probe_scan_launch",
-    "device_probe_scan_multi_launch",
     "device_probe_walk_batched_launch",
-    "device_probe_walk_launch",
     "merge_topk",
     "on_tpu",
     "pad_bucket",
@@ -51,14 +49,13 @@ __all__ = [
 # placed launch, ``launches.device.<dkey>``).
 # AMIH's batched verification asserts exactly one grouped launch per
 # (z-group, tuple-step) through this counter (see tests/test_verify_grouped);
-# the device probe path asserts O(1) launches per z-group through
-# "device_probe" (the fused walk) and "device_probe_scan" (the at-most-one
-# exhaustive fallback for truncated schedules).
+# the device probe path asserts one launch per batch through
+# "device_probe" (the fused walk); its scan fallback counts under
+# "scan_topk", the scan engine's program.
 
-# Guards the per-device jit instances and the position-map pool:
-# thread-mode shard probing (forced for the pallas verify backend)
-# dispatches launches from several threads, and dict check-then-insert
-# is not atomic.
+# Guards the per-device jit instances: thread-mode shard probing (forced
+# for the pallas verify backend) dispatches launches from several
+# threads, and dict check-then-insert is not atomic.
 _LAUNCH_LOCK = threading.Lock()
 
 
@@ -608,201 +605,11 @@ def _probe_put(arrays, device):
     return [jnp.asarray(a) for a in arrays]
 
 
-def device_probe_walk_launch(
-    q_words,
-    q_sub,
-    z_sub,
-    pow1,
-    pow0,
-    t_stop,
-    k: int,
-    *,
-    sched,
-    csr,
-    p: int,
-    device=None,
-    use_pallas: bool | None = None,
-    tile: int | None = None,
-    cap: int | None = None,
-    check_every: int | None = None,
-    walk_budget: int | None = None,
-) -> dict:
-    """Dispatch the fused probing-walk launch for one z-group.
-
-    ``sched`` is a ``repro.core.probe_device.DeviceSchedule`` and ``csr``
-    the index's committed CSR dict; per-call arrays (queries, substring
-    values/popcounts, flip tables, per-query stop positions) are padded to
-    a power-of-two batch and committed to ``device``. ``walk_budget``
-    caps the loop iterations (default: the point where one exhaustive
-    scan launch costs about as much as a quarter of the walk done so
-    far); still-undone queries fall through to the scan launch, exactly
-    as with a truncated schedule. Returns a host dict with the per-query
-    position map and counters, sliced back to B rows:
-    {"posmap", "probes", "retrieved", "done", "cursor", "iters"}.
-    """
-    from ..core.probe_device import (
-        DEFAULT_CHECK_EVERY,
-        DEFAULT_PROBE_CAP,
-        DEFAULT_TILE,
-        KMAX,
-    )
-    from . import device_probe
-
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    tile = DEFAULT_TILE if tile is None else tile
-    if tile > DEFAULT_TILE:
-        raise ValueError(
-            f"tile={tile} exceeds the schedule pad margin {DEFAULT_TILE}"
-        )
-    cap = pad_bucket(DEFAULT_PROBE_CAP if cap is None else cap, minimum=8)
-    check_every = (
-        DEFAULT_CHECK_EVERY if check_every is None else max(1, check_every)
-    )
-    if walk_budget is None:
-        # each iteration verifies <= cap candidates; the scan verifies
-        # n_pad rows in one launch. Past n_pad/(4*cap) iterations the
-        # walk has burned a quarter of a scan without converging — on a
-        # deep walk the exhaustive launch is the cheaper way to finish.
-        walk_budget = max(4, int(csr["n_pad"]) // (4 * cap))
-    qh = np.ascontiguousarray(np.asarray(q_words))
-    B = qh.shape[0]
-    Bp = pad_bucket(B, minimum=1)
-
-    def pad_rows(a, fill=0):
-        a = np.asarray(a)
-        out = np.full((Bp,) + a.shape[1:], fill, dtype=a.dtype)
-        out[:B] = a
-        return out
-
-    # padded query rows start with t_stop = -1: born done, so they never
-    # probe, never block done.all(), and cost nothing
-    per_call = _probe_put(
-        [
-            pad_rows(qh),
-            pad_rows(np.asarray(q_sub, dtype=np.int32)),
-            pad_rows(np.asarray(z_sub, dtype=np.int32)),
-            pad_rows(np.asarray(pow1, dtype=np.int32)),
-            pad_rows(np.asarray(pow0, dtype=np.int32)),
-            pad_rows(np.asarray(t_stop, dtype=np.int32), fill=-1),
-            np.int32(k),
-            np.int32(sched.s_len),
-            np.int32(walk_budget),
-        ],
-        device,
-    )
-    bundle = sched.device_arrays(device)
-    dkey = device_key(device)
-    _bump_launch("device_probe", dkey)
-    with _obs.current().span("launch.device_probe", cat="kernel",
-                             device=dkey, B=B):
-        posmap, probes, retrieved, done, cursor, iters = (
-            device_probe.device_probe_walk(
-                *per_call,
-                bundle["tbl"],
-                bundle["step_ext"],
-                bundle["idx1"],
-                bundle["idx0"],
-                bundle["maxi1"],
-                bundle["maxi0"],
-                bundle["widths"],
-                csr["offsets"],
-                csr["ids"],
-                csr["db_pad"],
-                bundle["inv_pos"],
-                p=p,
-                tile=tile,
-                cap=cap,
-                kmax=KMAX,
-                check_every=check_every,
-                use_pallas=use_pallas,
-                interpret=not on_tpu(),
-            )
-        )
-        count_d2h(posmap, probes, retrieved, done, cursor, iters)
-        return {
-            "posmap": np.asarray(posmap)[:B],
-            "probes": np.asarray(probes)[:B],
-            "retrieved": np.asarray(retrieved)[:B],
-            "done": np.asarray(done)[:B],
-            "cursor": int(cursor),
-            "iters": int(iters),
-        }
-
-
-def device_probe_scan_launch(
-    q_words,
-    *,
-    sched,
-    csr,
-    p: int,
-    device=None,
-    use_pallas: bool | None = None,
-    chunk: int = 2048,
-) -> np.ndarray:
-    """One exhaustive verify launch: the exact walk position of EVERY
-    stored code for each query — the fused scan fallback for queries a
-    truncated schedule left unfinished. Returns a host (B, n_pad) int32
-    position map."""
-    from . import device_probe
-
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    qh = np.ascontiguousarray(np.asarray(q_words))
-    B = qh.shape[0]
-    Bp = pad_bucket(B, minimum=1)
-    qp = np.zeros((Bp,) + qh.shape[1:], dtype=qh.dtype)
-    qp[:B] = qh
-    n_pad = csr["n_pad"]
-    chunk = min(pad_bucket(chunk, minimum=8), n_pad)
-    per_call = _probe_put([qp, np.int32(csr["n"])], device)
-    bundle = sched.device_arrays(device)
-    dkey = device_key(device)
-    _bump_launch("device_probe_scan", dkey)
-    with _obs.current().span("launch.device_probe_scan", cat="kernel",
-                             device=dkey, B=B):
-        pm = device_probe.device_probe_scan(
-            per_call[0],
-            csr["db_pad"],
-            bundle["inv_pos"],
-            per_call[1],
-            p=p,
-            chunk=chunk,
-            use_pallas=use_pallas,
-            interpret=not on_tpu(),
-        )
-        count_d2h(pm)
-        return np.asarray(pm)[:B]
-
-
-# Recycled (B_pad, n_pad) position-map scratch buffers, per placement
-# device: the fused batch walk donates its scratch input, so on backends
-# that honor donation (TPU/GPU) sustained serving reuses ONE buffer per
-# (device, batch-bucket, index) instead of allocating 4*B*n_pad bytes
-# every query batch. Keyed (device_key, B_pad, n_pad); small cap so odd
-# one-off batch shapes don't pin memory forever.
-_POSMAP_POOL: dict = {}
-_POSMAP_POOL_MAX = 2
-
-
-def _take_posmap(device, Bp: int, n_pad: int):
-    key = (device_key(device), Bp, n_pad)
-    with _LAUNCH_LOCK:
-        pool = _POSMAP_POOL.get(key)
-        if pool:
-            return key, pool.pop()
-    buf = np.zeros((Bp, n_pad), dtype=np.int32)
-    arr = jax.device_put(buf, device) if device is not None else (
-        jnp.asarray(buf)
-    )
-    return key, arr
-
-
-def _recycle_posmap(key, arr) -> None:
-    with _LAUNCH_LOCK:
-        pool = _POSMAP_POOL.setdefault(key, [])
-        if len(pool) < _POSMAP_POOL_MAX:
-            pool.append(arr)
+def carry_width(k: int) -> int:
+    """Slots of the walk's per-query (position, id) carry for a top-``k``
+    search: k rounded up to a multiple of the 128 lanes, so every k up to
+    128 shares one compiled walk."""
+    return -(-max(int(k), 1) // 128) * 128
 
 
 class PendingWalk:
@@ -811,35 +618,34 @@ class PendingWalk:
     Like ``PendingKeys``, holds the device output arrays without forcing
     a host sync, so the sharded engine can dispatch every device's fused
     launch back-to-back and only block at the final merge. ``get()``
-    materializes the host result dict (posmap is force-copied before the
-    output buffer is recycled into the donation pool — on CPU jax a
-    plain ``np.asarray`` may alias the device buffer the next launch
-    would overwrite)."""
+    copies the (B, kf) carries and the per-query counters to the host."""
 
-    __slots__ = ("_out", "_B", "_pool_key", "_res")
+    __slots__ = ("_out", "_B", "_dkey", "_res")
 
-    def __init__(self, out, B: int, pool_key):
+    def __init__(self, out, B: int, dkey: str):
         self._out = out
         self._B = B
-        self._pool_key = pool_key
+        self._dkey = dkey
         self._res = None
 
     def get(self) -> dict:
         if self._res is None:
             with _obs.current().span("launch.device_probe.resolve",
-                                     cat="kernel",
-                                     device=self._pool_key[0]):
+                                     cat="kernel", device=self._dkey):
                 count_d2h(*self._out)
-                posmap, probes, retrieved, done, cursor, iters = self._out
+                (pos, ids, probes, retrieved, verified, done, cursor,
+                 iters) = self._out
+                B = self._B
                 self._res = {
-                    "posmap": np.array(posmap)[: self._B],
-                    "probes": np.asarray(probes)[: self._B],
-                    "retrieved": np.asarray(retrieved)[: self._B],
-                    "done": np.asarray(done)[: self._B],
+                    "pos": np.asarray(pos)[:B],
+                    "ids": np.asarray(ids)[:B],
+                    "probes": np.asarray(probes)[:B],
+                    "retrieved": np.asarray(retrieved)[:B],
+                    "verified": np.asarray(verified)[:B],
+                    "done": np.asarray(done)[:B],
                     "cursor": np.asarray(cursor),
                     "iters": int(iters),
                 }
-                _recycle_posmap(self._pool_key, posmap)
                 self._out = None
         return self._res
 
@@ -861,28 +667,22 @@ def device_probe_walk_batched_launch(
     use_pallas: bool | None = None,
     tile: int | None = None,
     cap: int | None = None,
-    check_every: int | None = None,
     walk_budget: int | None = None,
-    blocking: bool = True,
-) -> "dict | PendingWalk":
+) -> PendingWalk:
     """Dispatch the fused cross-z-group walk: ONE launch for the whole
-    batch, every z-group included.
+    batch, every z-group included, and return a ``PendingWalk`` without
+    synchronizing (the sharded engine dispatches every device's walk
+    before it blocks on any).
 
     ``stack`` is a ``repro.core.probe_device.ScheduleStack`` (the grow-
-    only concatenation of the index's per-z schedules) and ``gid`` maps
-    each query to its stack row; everything else matches
-    ``device_probe_walk_launch``. With ``blocking=False`` returns a
-    ``PendingWalk`` handle instead of synchronizing — the sharded
-    engine's async multi-device dispatch. The (B_pad, n_pad) position-
-    map scratch is drawn from (and recycled to) a per-device donation
-    pool, so steady-state serving allocates nothing per batch on
-    backends that honor ``donate_argnames``."""
-    from ..core.probe_device import (
-        DEFAULT_CHECK_EVERY,
-        DEFAULT_PROBE_CAP,
-        DEFAULT_TILE,
-        KMAX,
-    )
+    only concatenation of the index's per-z schedules), ``gid`` maps
+    each query to its stack row and ``csr`` is the index's committed CSR
+    dict; per-call arrays (queries, substring values/popcounts, flip
+    tables, per-query stop positions) are padded to a power-of-two batch
+    and committed to ``device``. ``walk_budget`` caps the loop
+    iterations; still-undone queries are left to the caller's scan
+    fallback, exactly as with a truncated schedule."""
+    from ..core.probe_device import DEFAULT_PROBE_CAP, DEFAULT_TILE, KMAX
     from . import device_probe
 
     if use_pallas is None:
@@ -893,21 +693,15 @@ def device_probe_walk_batched_launch(
             f"tile={tile} exceeds the schedule pad margin {DEFAULT_TILE}"
         )
     cap = pad_bucket(DEFAULT_PROBE_CAP if cap is None else cap, minimum=8)
-    check_every = (
-        DEFAULT_CHECK_EVERY if check_every is None else max(1, check_every)
-    )
     qh = np.ascontiguousarray(np.asarray(q_words))
     B = qh.shape[0]
     Bp = pad_bucket(B, minimum=1)
     if walk_budget is None:
-        # an iteration of the fused walk probes a tile for EVERY query,
-        # so it costs ~Bp x the per-group iteration while the bail scan
-        # still covers only the undone subset. Scale the per-group
-        # crossover down by the batch width: past it, a few stragglers
-        # grinding the whole batch width cost more than one exhaustive
-        # scan over just those stragglers. At Bp=1 this is exactly the
-        # per-group budget; results are identical either way — bailed
-        # queries resolve exactly through the scan launch.
+        # each iteration verifies <= cap candidates for each of Bp
+        # queries; the scan verifies n_pad codes for the bailed ones.
+        # Past n_pad / (4 cap Bp) iterations the walk has burned a
+        # quarter of a scan's verifications without converging, and on
+        # a deep walk the exhaustive scan is the cheaper way to finish.
         walk_budget = max(4, int(csr["n_pad"]) // (4 * cap * Bp))
 
     def pad_rows(a, fill=0):
@@ -933,7 +727,6 @@ def device_probe_walk_batched_launch(
         device,
     )
     bundle = stack.device_arrays(device)
-    pool_key, posmap_in = _take_posmap(device, Bp, int(csr["n_pad"]))
     dkey = device_key(device)
     _bump_launch("device_probe", dkey)
     fn = _device_fn(
@@ -942,16 +735,13 @@ def device_probe_walk_batched_launch(
         lambda: jax.jit(
             device_probe.device_probe_walk_batched,
             static_argnames=(
-                "p", "tile", "cap", "kmax", "check_every",
-                "use_pallas", "interpret",
+                "p", "tile", "cap", "kf", "kmax", "use_pallas", "interpret",
             ),
-            donate_argnames=("posmap_in",),
         ),
     )
     with _obs.current().span("launch.device_probe.dispatch", cat="kernel",
                              device=dkey, B=B):
         out = fn(
-            posmap_in,
             *per_call,
             bundle["g_start"],
             bundle["g_end"],
@@ -961,7 +751,9 @@ def device_probe_walk_batched_launch(
             bundle["idx0"],
             bundle["maxi1"],
             bundle["maxi0"],
+            bundle["block_start"],
             bundle["widths"],
+            csr["sub_masks"],
             csr["offsets"],
             csr["ids"],
             csr["db_pad"],
@@ -969,70 +761,12 @@ def device_probe_walk_batched_launch(
             p=p,
             tile=tile,
             cap=cap,
+            kf=carry_width(k),
             kmax=KMAX,
-            check_every=check_every,
             use_pallas=use_pallas,
             interpret=not on_tpu(),
         )
-    pending = PendingWalk(out, B, pool_key)
-    return pending.get() if blocking else pending
-
-
-def device_probe_scan_multi_launch(
-    q_words,
-    gid,
-    *,
-    stack,
-    csr,
-    p: int,
-    device=None,
-    use_pallas: bool | None = None,
-    chunk: int = 2048,
-) -> np.ndarray:
-    """One exhaustive verify launch across EVERY bailed z-group: the
-    fused form of ``device_probe_scan_launch`` with a per-query ``gid``
-    row into the stack's inverse-position tables. Returns a host
-    (B, n_pad) int32 position map."""
-    from . import device_probe
-
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    qh = np.ascontiguousarray(np.asarray(q_words))
-    B = qh.shape[0]
-    Bp = pad_bucket(B, minimum=1)
-    qp = np.zeros((Bp,) + qh.shape[1:], dtype=qh.dtype)
-    qp[:B] = qh
-    gp = np.zeros(Bp, dtype=np.int32)
-    gp[:B] = np.asarray(gid, dtype=np.int32)
-    n_pad = csr["n_pad"]
-    chunk = min(pad_bucket(chunk, minimum=8), n_pad)
-    per_call = _probe_put([qp, gp, np.int32(csr["n"])], device)
-    bundle = stack.device_arrays(device)
-    dkey = device_key(device)
-    _bump_launch("device_probe_scan", dkey)
-    fn = _device_fn(
-        device,
-        "scan_multi",
-        lambda: jax.jit(
-            device_probe.device_probe_scan_multi,
-            static_argnames=("p", "chunk", "use_pallas", "interpret"),
-        ),
-    )
-    with _obs.current().span("launch.device_probe_scan", cat="kernel",
-                             device=dkey, B=B):
-        pm = fn(
-            per_call[0],
-            per_call[1],
-            csr["db_pad"],
-            bundle["inv_pos"],
-            per_call[2],
-            p=p,
-            chunk=chunk,
-            use_pallas=use_pallas,
-            interpret=not on_tpu(),
-        )
-        count_d2h(pm)
-        return np.asarray(pm)[:B]
+    return PendingWalk(out, B, dkey)
 
 
 def verify_tuples_grouped_op(

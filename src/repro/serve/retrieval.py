@@ -70,13 +70,11 @@ class RetrievalConfig:
     # "device" (the fused probe -> bucket-lookup -> verify jitted launch;
     # see core.probe_device). Applies to "amih" and "sharded_amih";
     # probe_stream_cap bounds the precompiled probing stream per (p, z)
-    # schedule before the scan fallback takes over. probe_fused (default)
+    # schedule before the scan fallback takes over. The device walk
     # stacks every z-group into ONE launch per batch — and, sharded, one
-    # fused launch per DEVICE, dispatched to all devices without blocking
-    # — False restores the per-z-group launches as a parity oracle.
+    # fused launch per DEVICE, dispatched to all devices without blocking.
     probe_backend: str = "host"
     probe_stream_cap: int = 1 << 16
-    probe_fused: bool = True
     # linear_scan scoring: "numpy" (chunked host popcounts) or "pallas"
     # (streaming device top-K via kernels/ops.scan_topk + exact float64
     # host rerank).
@@ -258,7 +256,6 @@ class RetrievalService:
                 "overlap_verify": self.rcfg.pipelined,
                 "probe_backend": self.rcfg.probe_backend,
                 "probe_stream_cap": self.rcfg.probe_stream_cap,
-                "probe_fused": self.rcfg.probe_fused,
             }
         elif self.rcfg.backend == "linear_scan":
             cfg = {"compute_backend": self.rcfg.compute_backend}
@@ -276,7 +273,6 @@ class RetrievalService:
                 "probe_mode": self.rcfg.probe_mode,
                 "probe_backend": self.rcfg.probe_backend,
                 "probe_stream_cap": self.rcfg.probe_stream_cap,
-                "probe_fused": self.rcfg.probe_fused,
             }
         backend = self.rcfg.backend
         if self.rcfg.cluster:
@@ -299,7 +295,6 @@ class RetrievalService:
                     enumeration_cap=self.rcfg.enumeration_cap,
                     probe_backend=self.rcfg.probe_backend,
                     probe_stream_cap=self.rcfg.probe_stream_cap,
-                    probe_fused=self.rcfg.probe_fused,
                 )
             backend = "cluster"
         if self.rcfg.trace:

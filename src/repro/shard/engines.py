@@ -314,9 +314,8 @@ class ShardedAMIHEngine(SearchEngine):
 
     ``probe_backend="device"`` builds every shard index with the fused
     device probing walk (see core.probe_device), so the host probe pool
-    stands down entirely — no workers ever fork. With ``probe_fused``
-    (the default) the engine goes further and collapses the launch count
-    to O(devices): the shards resident on each device are stacked into
+    stands down entirely — no workers ever fork, and the engine
+    collapses the launch count to O(devices): the shards resident on each device are stacked into
     one per-device *super index* (concatenated rows + rebuilt CSR, local
     rows mapped back to global ids at extraction), every device's fused
     batch walk is dispatched WITHOUT blocking, and the host only syncs
@@ -353,8 +352,7 @@ class ShardedAMIHEngine(SearchEngine):
                  probe_workers: Optional[int] = None,
                  prime_bound: bool = True,
                  probe_mode: str = "auto",
-                 probe_backend: str = "host",
-                 probe_fused: bool = True):
+                 probe_backend: str = "host"):
         self.db_words = np.ascontiguousarray(db_words, dtype=WORD_DTYPE)
         self.p = p
         self.plan = plan
@@ -364,7 +362,6 @@ class ShardedAMIHEngine(SearchEngine):
         self.prime_bound = prime_bound
         self.probe_mode = probe_mode
         self.probe_backend = probe_backend
-        self.probe_fused = probe_fused
         self._fused = None          # per-device super-index groups, lazy
         self._fused_seq = 0         # shared launch-id counter (S6)
         self._pool = None           # PersistentShardPool, forked on first use
@@ -390,7 +387,6 @@ class ShardedAMIHEngine(SearchEngine):
         probe_mode: str = "auto",
         probe_backend: str = "host",
         probe_stream_cap: int = 1 << 16,
-        probe_fused: bool = True,
         devices=None,
         **cfg: Any,
     ) -> "ShardedAMIHEngine":
@@ -412,11 +408,9 @@ class ShardedAMIHEngine(SearchEngine):
                 device=plan.device_for(s),
                 probe_backend=probe_backend,
                 probe_stream_cap=probe_stream_cap,
-                probe_fused=probe_fused,
             )))
         return cls(db, p, plan, indexes, enumeration_cap,
-                   probe_workers, prime_bound, probe_mode, probe_backend,
-                   probe_fused)
+                   probe_workers, prime_bound, probe_mode, probe_backend)
 
     @property
     def n(self) -> int:
@@ -628,7 +622,7 @@ class ShardedAMIHEngine(SearchEngine):
 
         Returns None — and the caller falls back to the sequential
         chain — unless every shard index runs ``probe_backend="device"``
-        with ``probe_fused`` and all shards agree on (m, stream cap), so
+        and all shards agree on (m, stream cap), so
         a mixed or per-shard-tuned layout never silently changes shape.
 
         Each group stacks the shards resident on ONE device: a
@@ -640,11 +634,7 @@ class ShardedAMIHEngine(SearchEngine):
         order, concat-row order equals global-id order within the
         device, so extraction order — hence the final lexsort merge —
         is bit-identical to the sequential per-shard path."""
-        if (
-            self.probe_backend != "device"
-            or not self.probe_fused
-            or not self.indexes
-        ):
+        if self.probe_backend != "device" or not self.indexes:
             return None
         if self._fused is not None:
             return self._fused
@@ -653,7 +643,6 @@ class ShardedAMIHEngine(SearchEngine):
         if (
             len({ix.m for _, ix in self.indexes}) > 1
             or len({ix.probe_stream_cap for _, ix in self.indexes}) > 1
-            or not all(ix.probe_fused for _, ix in self.indexes)
             or not all(
                 ix.probe_backend == "device" for _, ix in self.indexes
             )

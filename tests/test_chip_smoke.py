@@ -49,9 +49,10 @@ def test_single_chip_phases_exact_with_launches_counted():
     assert all(r["exact"] and r["p"] == 64 and r["B"] == 8 for r in rows)
     walk = [r for r in rows if r["phase"] == "amih_device_walk"]
     assert [r["launches"]["launches.device_probe"] for r in walk] == [1, 1]
-    # K=100 over 4096 codes bails every query to the scan fallback
+    # K=100 over 4096 codes bails every query to the scan fallback, one
+    # launch of the scan engine's program
     assert walk[1]["fell_back"] == 8
-    assert walk[1]["launches"]["launches.device_probe_scan"] == 1
+    assert walk[1]["launches"]["launches.scan_topk"] == 1
     assert [r["launches"][on_device] for r in walk] == [1, 2]
     verify = [r for r in rows if r["phase"] == "amih_pallas_verify"]
     assert all(r["launches"]["launches.verify_grouped"] >= 1 for r in verify)

@@ -80,13 +80,13 @@ def test_scan_topk_pallas_compiles(one_chip, W, monkeypatch):
 
 @pytest.fixture(scope="module")
 def walk_calls():
-    """The exact arguments of the fused walk and the scan fallback, caught
-    from a device-probe search over a small index on the CPU."""
+    """The exact arguments of the fused walk and of its scan fallback,
+    caught from a device-probe search over a small index on the CPU."""
     calls = {}
-    real = ops._device_fn
+    real_fn, real_scan = ops._device_fn, ops.scan_topk
 
     def catching(device, name, make):
-        fn = real(device, name, make)
+        fn = real_fn(device, name, make)
 
         def call(*args, **kw):
             calls.setdefault(name, (args, kw))
@@ -94,33 +94,42 @@ def walk_calls():
 
         return call
 
+    def scan(*args, **kw):
+        calls.setdefault("scan_fallback", (args, kw))
+        return real_scan(*args, **kw)
+
     p, n, B = 64, 1 << 12, 8
     db_bits = synthetic_binary_codes(n, p, seed=0)
     q = pack_bits(synthetic_queries(db_bits, B, seed=1))
-    eng = make_engine("amih", pack_bits(db_bits), p, m=4,
+    eng = make_engine("amih", pack_bits(db_bits), p, m=3,
                       probe_backend="device", query_cache_size=0)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_device_fn", catching)
+        mp.setattr(ops, "scan_topk", scan)
         eng.knn_batch(q, 100)      # K=100 bails part of the batch to the scan
-    assert set(calls) == {"walk_batched", "scan_multi"}, sorted(calls)
+    assert set(calls) == {"walk_batched", "scan_fallback"}, sorted(calls)
     return calls
 
 
-@pytest.mark.parametrize(
-    "name, fn, static",
-    [
-        ("walk_batched", device_probe.device_probe_walk_batched,
-         ("p", "tile", "cap", "kmax", "check_every", "use_pallas",
-          "interpret")),
-        ("scan_multi", device_probe.device_probe_scan_multi,
-         ("p", "chunk", "use_pallas", "interpret")),
-    ],
-)
-def test_device_probe_pallas_compiles(one_chip, walk_calls, name, fn,
-                                      static):
-    args, kw = walk_calls[name]
+def test_device_probe_pallas_compiles(one_chip, walk_calls):
+    args, kw = walk_calls["walk_batched"]
     kw = dict(kw, use_pallas=True, interpret=False)
     specs = [_spec(a, one_chip) for a in args]
+    fn = jax.jit(device_probe.device_probe_walk_batched,
+                 static_argnames=("p", "tile", "cap", "kf", "kmax",
+                                  "use_pallas", "interpret"))
+    _assert_kernel(fn.lower(*specs, **kw).compile())
+
+
+def test_scan_fallback_pallas_compiles(one_chip, walk_calls, monkeypatch):
+    # scan_scores picks interpret mode from the default backend, which
+    # is the CPU here: steer it to the chip's path for this compile
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    (q, db, k), kw = walk_calls["scan_fallback"]
+    kw = dict(kw, use_pallas=True)
+    if "n_valid" in kw:
+        kw["n_valid"] = _spec(kw["n_valid"], one_chip)
     _assert_kernel(
-        jax.jit(fn, static_argnames=static).lower(*specs, **kw).compile()
+        ops.scan_topk.lower(_spec(q, one_chip), _spec(db, one_chip), k,
+                            **kw).compile()
     )
