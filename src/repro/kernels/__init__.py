@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
-- hamming_scan: streaming XOR+popcount+Eq.3 scoring (linear-scan baseline,
-  distributed reranker)
+- hamming_scan: streaming AND-popcount + Eq.3 scoring over word planes of
+  the codes (linear-scan baseline, distributed reranker)
 - verify_tuples: batched exact-tuple verification (AMIH candidate
   pruning); verify_tuples_grouped runs a whole z-group per launch over a
   padded (B, C, W) layout with in-kernel padding masks and fused

@@ -20,7 +20,7 @@ import numpy as np
 from ..obs import trace as _obs
 from ..obs.metrics import REGISTRY as _REG
 from . import ref
-from .hamming_scan import DEFAULT_BLK_N, DEFAULT_BLK_Q, hamming_scan_scores
+from .hamming_scan import code_planes, hamming_scan_scores
 from .verify_tuples import DEFAULT_BLK_C
 from .verify_tuples import verify_tuples as _verify_tuples_kernel
 from .verify_tuples import verify_tuples_grouped as _verify_grouped_kernel
@@ -112,8 +112,6 @@ def scan_scores(
     db_words: jax.Array,
     *,
     use_pallas: bool | None = None,
-    blk_n: int = DEFAULT_BLK_N,
-    blk_q: int = DEFAULT_BLK_Q,
 ) -> jax.Array:
     """(B, W), (N, W) -> (B, N) Eq.3 cosine scores (float32).
 
@@ -121,20 +119,16 @@ def scan_scores(
     elsewhere only for modest sizes (interpret mode is a correctness tool,
     not a fast CPU path); the jnp reference is semantically identical.
     """
-    B, _ = q_words.shape
     N, _ = db_words.shape
-    z_q = ref.popcount32(q_words.astype(jnp.uint32)).sum(axis=-1)
     if use_pallas is None:
         use_pallas = on_tpu()
     if not use_pallas:
+        z_q = ref.popcount32(q_words.astype(jnp.uint32)).sum(axis=-1)
         return ref.scores_ref(q_words, db_words, z_q)
-    qp = _pad_to(q_words, 0, blk_q)
-    zp = _pad_to(z_q, 0, blk_q)
-    dbp = _pad_to(db_words, 0, blk_n)
-    sims = hamming_scan_scores(
-        qp, zp, dbp, blk_n=blk_n, blk_q=blk_q, interpret=not on_tpu()
+    return hamming_scan_scores(
+        q_words, code_planes(db_words, N), jnp.int32(0), chunk=N,
+        interpret=not on_tpu(),
     )
-    return sims[:B, :N]
 
 
 # Codes per block of scan_topk's streaming merge.
@@ -250,9 +244,11 @@ def scan_topk(
     """Streaming exact angular top-K: (B, W) x (N, W) -> sims, ids (B, k).
 
     The DB is processed in chunks with a running top-K merge
-    (lax.scan carry), so peak memory is O(B * (k + chunk)) regardless of N:
-    one chunk's (B, chunk) scores and the merge's sort operands, at most
-    k + chunk columns of them.
+    (lax.scan carry), so peak memory is O(B * (k + chunk)) beside the
+    codes: one chunk's (B, chunk) scores and the merge's sort operands,
+    at most k + chunk columns of them. The Pallas path also holds one
+    relaid copy of the codes, their word planes (``code_planes``), which
+    each step's kernel reads in place at its chunk's offset.
     This is the device-side linear-scan baseline *and* the reranker of the
     distributed retrieval path.
 
@@ -289,26 +285,39 @@ def scan_topk(
     chunk = min(chunk, N)
     n_chunks = (N + chunk - 1) // chunk
     padded_n = n_chunks * chunk
-    dbp = jnp.pad(db_words, ((0, padded_n - N), (0, 0)))
-    dbp = dbp.reshape(n_chunks, chunk, W)
     row_ids = jnp.arange(padded_n).reshape(n_chunks, chunk)
     base_valid = row_ids < N
     if n_valid is not None:
         base_valid = base_valid & (row_ids < n_valid)
+    chunk_ids = jnp.arange(n_chunks, dtype=jnp.int32)
+
+    if use_pallas:
+        # the codes relaid once into word planes; a step's kernel reads
+        # its chunk of them in place
+        planes = code_planes(db_words, chunk)
+
+        def scores(chunk_idx):
+            return hamming_scan_scores(q_words, planes, chunk_idx,
+                                       chunk=chunk, interpret=not on_tpu())
+
+        xs = chunk_ids
+    else:
+        def scores(db_chunk):
+            return scan_scores(q_words, db_chunk, use_pallas=False)
+
+        xs = jnp.pad(db_words, ((0, padded_n - N), (0, 0)))
+        xs = xs.reshape(n_chunks, chunk, W)
 
     init_sims = jnp.full((B, k), -jnp.inf, dtype=jnp.float32)
     init_ids = jnp.full((B, k), -1, dtype=jnp.int32)
 
     def step(carry, inp):
-        db_chunk, valid, chunk_idx = inp
-        sims = scan_scores(q_words, db_chunk, use_pallas=use_pallas)
-        sims = jnp.where(valid[None, :], sims, -jnp.inf)
+        x, valid, chunk_idx = inp
+        sims = jnp.where(valid[None, :], scores(x), -jnp.inf)
         return _merge_topk_chunk(*carry, sims, chunk_idx * chunk, k, g), None
 
     (sims, ids), _ = jax.lax.scan(
-        step,
-        (init_sims, init_ids),
-        (dbp, base_valid, jnp.arange(n_chunks, dtype=jnp.int32)),
+        step, (init_sims, init_ids), (xs, base_valid, chunk_ids)
     )
     return sims, ids
 

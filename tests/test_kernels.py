@@ -46,6 +46,36 @@ def test_hamming_scan_kernel_sweep(rng, p, shape):
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+def _exact_score_codes(rng, n, p):
+    """Codes of weight 0, 1, 4, 16 or 64 (at most p): every product
+    |q| |b| is a power of four, so every Eq. 3 score c / sqrt(|q| |b|)
+    is a dyadic float that rsqrt and 1 / sqrt both give exactly."""
+    weights = [w for w in (0, 1, 4, 16, 64) if w <= p]
+    bits = np.zeros((n, p), np.uint8)
+    for row, w in zip(bits, rng.choice(weights, n)):
+        row[rng.choice(p, w, replace=False)] = 1
+    return pack_bits(bits)
+
+
+@pytest.mark.parametrize("p", [32, 64, 128])
+@pytest.mark.parametrize("B", [1, 8, 64])
+@pytest.mark.parametrize("N", [3000, 16000])
+def test_hamming_scan_kernel_bit_identical(rng, p, B, N):
+    # N is no multiple of the kernel's tile (1,024 codes at N = 3000;
+    # 8,192 or 16,384 at N = 16000, so several tiles at B = 64)
+    q = _exact_score_codes(rng, B, p)
+    q[0] = 0                                       # a zero query
+    db = _exact_score_codes(rng, N, p)
+    db[[5, N - 1]] = 0                             # zero codes
+    z = np.bitwise_count(q).sum(axis=1)
+    got = np.asarray(ops.scan_scores(jnp.asarray(q), jnp.asarray(db),
+                                     use_pallas=True))
+    want = np.asarray(ref.scores_ref(jnp.asarray(q), jnp.asarray(db),
+                                     jnp.asarray(z)))
+    np.testing.assert_array_equal(got, want)
+    assert np.all(got[0] == 0.0) and np.all(got[:, [5, N - 1]] == 0.0)
+
+
 @pytest.mark.parametrize("p", [32, 64, 128])
 @pytest.mark.parametrize("n", [64, 1000, 3000])
 def test_verify_tuples_kernel_sweep(rng, p, n):
@@ -206,6 +236,28 @@ def test_scan_topk_two_stage_matches_one_stage(
     got = ops.scan_topk(q, db, k, chunk=chunk, use_pallas=use_pallas,
                         n_valid=n_valid)
     want = _one_stage_scan_topk(q, db, k, chunk, use_pallas, n_valid)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("B", [1, 8, 64, 72])
+@pytest.mark.parametrize("chunk,N", [(1000, 2500), (4096, 9000),
+                                     (1 << 16, 20000)])
+@pytest.mark.parametrize("masked", [False, True])
+def test_scan_topk_kernel_matches_one_stage_reference(rng, B, chunk, N,
+                                                     masked):
+    # the kernel's tiles against the XLA reference scores and the direct
+    # merge: chunks that pad to whole tiles, several chunks and one, and
+    # 72 queries in two 64-row query blocks
+    k = 100
+    db = jnp.asarray(_weight16_codes(rng, N, 40))
+    q = jnp.asarray(np.concatenate(
+        [_weight16_codes(rng, B - 1, 40), np.zeros((1, 2), np.uint32)]
+    ))
+    n_valid = jnp.int32(N - 777) if masked else None
+    got = ops.scan_topk(q, db, k, chunk=chunk, use_pallas=True,
+                        n_valid=n_valid)
+    want = _one_stage_scan_topk(q, db, k, chunk, False, n_valid)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 

@@ -133,3 +133,16 @@ def test_scan_fallback_pallas_compiles(one_chip, walk_calls, monkeypatch):
         ops.scan_topk.lower(_spec(q, one_chip), _spec(db, one_chip), k,
                             **kw).compile()
     )
+
+
+def test_scan_topk_full_size_temporaries_under_1gib(one_chip, monkeypatch):
+    # the benchmark cells' scan at 2^24 codes: the codes' word planes and
+    # one chunk's scores, not the tile-padded code copy (8.59 GB) the
+    # kernel's former (1024, W) code blocks made XLA write
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    B, N, W = 64, 1 << 24, 2
+    q = jax.ShapeDtypeStruct((B, W), jnp.uint32, sharding=one_chip)
+    db = jax.ShapeDtypeStruct((N, W), jnp.uint32, sharding=one_chip)
+    compiled = ops.scan_topk.lower(q, db, 128, use_pallas=True).compile()
+    _assert_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
